@@ -231,7 +231,7 @@ def cluster_hetero(arrival: str = "batch", quick: bool = False):
     from repro.serving.workload import make_arrivals, synthetic_requests
 
     cfg = get_config("granite-8b").reduced()
-    params = zoo.init_state(cfg, jax.random.PRNGKey(0)).params
+    params = zoo.init_params(cfg, jax.random.PRNGKey(0))
     fleet = [InstanceType("fast.2x", 2.0), InstanceType("fast.2x", 2.0),
              InstanceType("slow.1x", 0.7), InstanceType("slow.1x", 0.7)]
     n_requests, max_seq = (12, 32) if quick else (24, 48)
@@ -304,7 +304,7 @@ def cluster_slo(quick: bool = False):
                                         classed_requests)
 
     cfg = get_config("granite-8b").reduced()
-    params = zoo.init_state(cfg, jax.random.PRNGKey(0)).params
+    params = zoo.init_params(cfg, jax.random.PRNGKey(0))
     fleet = [InstanceType("fast.2x", 2.0), InstanceType("fast.2x", 2.0),
              InstanceType("slow.1x", 0.7), InstanceType("slow.1x", 0.7)]
     interactive = SLOClass("interactive", 0, deadline=12.0)
@@ -402,7 +402,7 @@ def cluster_preempt(quick: bool = False):
     from repro.serving.workload import SLOClass
 
     cfg = get_config("granite-8b").reduced()
-    params = zoo.init_state(cfg, jax.random.PRNGKey(0)).params
+    params = zoo.init_params(cfg, jax.random.PRNGKey(0))
     n_rep = 2 if quick else 3
     fleet = [InstanceType("std.1x", 1.0, cost_per_hour=1.0)
              for _ in range(n_rep)]
@@ -522,7 +522,7 @@ def cluster_vertical(quick: bool = False):
     from repro.vertical import FixedThresholdVertical, QoSPolicy
 
     cfg = get_config("granite-8b").reduced()
-    params = zoo.init_state(cfg, jax.random.PRNGKey(0)).params
+    params = zoo.init_params(cfg, jax.random.PRNGKey(0))
     n_rep = 2
     base_batch, max_batch = 2, 4
     fleet = [InstanceType("std.1x", 1.0, spot=False, cost_per_hour=1.0)
@@ -665,7 +665,7 @@ def cluster_spot_market(quick: bool = False):
                                         classed_requests)
 
     cfg = get_config("granite-8b").reduced()
-    params = zoo.init_state(cfg, jax.random.PRNGKey(0)).params
+    params = zoo.init_params(cfg, jax.random.PRNGKey(0))
     n_rep, n_requests, rate = (2, 14, 2.5) if quick else (3, 30, 2.0)
     fleet = [InstanceType("std.1x", 1.0, cost_per_hour=1.0)
              for _ in range(n_rep)]
@@ -779,7 +779,7 @@ def cluster_chaos(quick: bool = False):
     from repro.serving.workload import synthetic_requests
 
     cfg = get_config("granite-8b").reduced()
-    params = zoo.init_state(cfg, jax.random.PRNGKey(0)).params
+    params = zoo.init_params(cfg, jax.random.PRNGKey(0))
     n_requests = 12 if quick else 20
     fleet = [InstanceType("std.1x", 1.0, cost_per_hour=1.0)
              for _ in range(2)]
@@ -879,7 +879,7 @@ def engine_throughput(quick: bool = False):
     from repro.serving.workload import prefill_heavy_requests
 
     cfg = get_config("granite-8b").reduced()
-    params = zoo.init_state(cfg, jax.random.PRNGKey(0)).params
+    params = zoo.init_params(cfg, jax.random.PRNGKey(0))
     max_seq, prompt_len = 96, 64
     max_new = 4 if quick else 16
     reps = 2 if quick else 4
@@ -985,7 +985,7 @@ def engine_churn(quick: bool = False):
     from repro.serving.workload import synthetic_requests
 
     cfg = get_config("granite-8b").reduced()
-    params = zoo.init_state(cfg, jax.random.PRNGKey(0)).params
+    params = zoo.init_params(cfg, jax.random.PRNGKey(0))
     max_seq, block, dense_lanes, paged_lanes = 64, 8, 4, 8
     pool = dense_lanes * (max_seq // block)   # == dense kv memory
     n_req = 16 if quick else 40
@@ -1245,6 +1245,8 @@ def main() -> None:
         ap.error(f"unknown section(s): {sorted(unknown)}; "
                  f"choose from {sorted(known)}")
     opts = {"arrival": args.arrival, "quick": args.quick}
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for fn in SECTIONS:
         if names and fn.__name__ not in names:

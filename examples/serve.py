@@ -10,7 +10,7 @@ from repro.models import model_zoo as zoo
 from repro.serving.engine import Request, ServingEngine
 
 cfg = get_config("llama3.2-3b").reduced()
-params = zoo.init_state(cfg, jax.random.PRNGKey(0)).params
+params = zoo.init_params(cfg, jax.random.PRNGKey(0))
 engine = ServingEngine(cfg, params, batch_size=4, max_seq=64)
 
 rng = np.random.default_rng(0)

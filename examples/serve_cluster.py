@@ -19,7 +19,7 @@ from repro.models import model_zoo as zoo
 from repro.serving.workload import PoissonArrivals, synthetic_requests
 
 cfg = get_config("granite-8b").reduced()
-params = zoo.init_state(cfg, jax.random.PRNGKey(0)).params
+params = zoo.init_params(cfg, jax.random.PRNGKey(0))
 fleet = [InstanceType("fast.2x", 2.0), InstanceType("fast.2x", 2.0),
          InstanceType("slow.1x", 0.7), InstanceType("slow.1x", 0.7)]
 

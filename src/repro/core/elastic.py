@@ -45,13 +45,16 @@ class ElasticRuntime:
     ``mesh_factory(n_devices)``   -> Mesh using the first n devices
     ``shardings_factory(mesh)``   -> (in_shardings pytree for the state)
     ``step_factory(mesh)``        -> jitted step fn(state, batch)
+    ``init_fn(shardings)``        -> initial state, created in place in
+                                     those shardings (never whole on one
+                                     device first)
 
     The runtime owns the current mesh/state and performs the 5-stage
     rescale protocol; the CloudManager calls ``rescale_to``.
     """
 
     def __init__(self, *, mesh_factory, shardings_factory, step_factory,
-                 init_state, n_devices: int,
+                 init_fn: Callable[[Any], Any], n_devices: int,
                  store: Optional[InMemoryStore] = None):
         self.mesh_factory = mesh_factory
         self.shardings_factory = shardings_factory
@@ -62,8 +65,7 @@ class ElasticRuntime:
         self.mesh = mesh_factory(n_devices)
         self.shardings = shardings_factory(self.mesh)
         self._step = step_factory(self.mesh)
-        self.state = jax.tree.map(
-            lambda x, s: jax.device_put(x, s), init_state, self.shardings)
+        self.state = init_fn(self.shardings)
 
     def step(self, batch):
         self.state, out = self._step(self.state, batch)

@@ -76,8 +76,7 @@ def make_jacobi_spmd_step(mesh: Mesh, *, axis: str = "data", odf: int = 4,
             tiles, _ = jax.lax.scan(one_iter, tiles, None, length=n_iters)
             return tiles.reshape(rows_total, W)
 
-        from repro.core.compat import shard_map
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(axis, None),
             out_specs=P(axis, None))(grid)
 

@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -85,10 +86,6 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 512,
     kernel = functools.partial(_flash_kernel, block_q=block_q,
                                block_kv=block_kv, causal=causal,
                                scale=scale)
-    from jax.experimental.pallas import tpu as pltpu
-    # renamed TPUCompilerParams -> CompilerParams across pallas releases
-    compiler_params_cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
     return pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
@@ -108,7 +105,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 512,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=compiler_params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -2,21 +2,27 @@
 
 One query token per lane attends over its lane's KV blocks, addressed
 through a scalar-prefetched block table (vLLM-style paging).  Grid =
-(batch, kv_heads, max_blocks); the block axis is the innermost
-("arbitrary") dimension so the online-softmax state for one (lane, head)
-lives in VMEM scratch across block iterations.  The block table and the
-per-lane valid length ride in as scalar-prefetch operands
-(``PrefetchScalarGridSpec``): the kv BlockSpec index map reads
-``block_tables[lane, j]`` to pull the j-th logical block's *physical*
-pool row into VMEM — no gather materialization.
+(batch, max_blocks); the block axis is the innermost ("arbitrary")
+dimension so the online-softmax state for one lane lives in VMEM scratch
+across block iterations.  The block table and the per-lane valid length
+ride in as scalar-prefetch operands (``PrefetchScalarGridSpec``): the kv
+BlockSpec index map reads ``block_tables[lane, j]`` to pull the j-th
+logical block's *physical* pool row into VMEM — no gather
+materialization.
+
+Each grid step moves one whole pool row, ``(bs, KV, D)``: the block's
+last two dims equal the pool's, which is what Mosaic's tiling rule
+accepts, and one DMA per block serves every kv head.  The kernel then
+walks the kv heads with static slices; GQA lays q out as (B, KV, G, D)
+so each head's whole query group is one (G, D) tile.
 
 Blocks wholly past ``kv_len`` are skipped with ``pl.when`` (true block
 skipping); the partial tail block masks positions >= kv_len to an
-exact-zero softmax weight.  GQA: q is laid out (B, KV, G, D) so one grid
-cell covers a kv head's whole query group.
+exact-zero softmax weight.
 
-VMEM working set: q(G,d) + k,v(bs,d) + acc(G,d)f32 + m,l(G,1)f32 — tiny;
-the pool itself stays in HBM and only table-addressed blocks move.
+VMEM working set: q(KV,G,d) + k,v(bs,KV,d) + acc(KV,G,d)f32 +
+m,l(KV,G,1)f32 — tiny; the pool itself stays in HBM and only
+table-addressed blocks move.
 """
 
 from __future__ import annotations
@@ -26,6 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -33,8 +40,8 @@ NEG_INF = -1e30
 def _paged_kernel(bt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, block_size: int, scale: float):
     ib = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
@@ -46,28 +53,30 @@ def _paged_kernel(bt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j * block_size < klen)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)          # (G, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)       # (bs, d)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < klen, s, NEG_INF)
-        m_prev = m_ref[...]                           # (G, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for h in range(q_ref.shape[1]):
+            q = q_ref[0, h].astype(jnp.float32)          # (G, d)
+            k = k_ref[0, :, h, :].astype(jnp.float32)    # (bs, d)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            pos = j * block_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(pos < klen, s, NEG_INF)
+            m_prev = m_ref[h]                            # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(j == nj - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, kv_len, *,
@@ -84,35 +93,26 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_len, *,
     qg = q.reshape(b, kv, group, d)
 
     kernel = functools.partial(_paged_kernel, block_size=bs, scale=scale)
-    from jax.experimental.pallas import tpu as pltpu
-    # renamed TPUCompilerParams -> CompilerParams across pallas releases
-    compiler_params_cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
+    lane = pl.BlockSpec((1, kv, group, d), lambda ib, j, bt, kl: (ib, 0, 0, 0))
+    pool_row = pl.BlockSpec((1, bs, kv, d),
+                            lambda ib, j, bt, kl: (bt[ib, j], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, d),
-                         lambda ib, ih, j, bt, kl: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda ib, ih, j, bt, kl: (bt[ib, j], 0, ih, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda ib, ih, j, bt, kl: (bt[ib, j], 0, ih, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, d),
-                               lambda ib, ih, j, bt, kl: (ib, ih, 0, 0)),
+        grid=(b, max_blocks),
+        in_specs=[lane, pool_row, pool_row],
+        out_specs=lane,
         scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((kv, group, d), jnp.float32),
+            pltpu.VMEM((kv, group, 1), jnp.float32),
+            pltpu.VMEM((kv, group, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, group, d), q.dtype),
-        compiler_params=compiler_params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
       qg, k_pool, v_pool)

@@ -1,18 +1,21 @@
 """Serving launcher: continuous-batching engine over any --arch.
 
-CPU-scale demo (reduced config):
+Without ``--reduced`` the model is built at its published widths with
+bf16 params (no optimizer state) -- an accelerator-sized job.  CPU demos
+pass ``--reduced``:
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b --reduced \
       --requests 8 --max-new 8
 
 Cluster mode — replicated engines on a heterogeneous spot fleet, with
 rate-aware routing and a drained interruption:
-  PYTHONPATH=src python -m repro.launch.serve --cluster --fleet 2x2.0,2x0.7 \
-      --router rate_aware --requests 24 --interrupt-at 4
+  PYTHONPATH=src python -m repro.launch.serve --reduced --cluster \
+      --fleet 2x2.0,2x0.7 --router rate_aware --requests 24 --interrupt-at 4
 
 Chaos drill — seeded fault soup (hard kills, slowdowns, contention,
 endpoint failures) survived via checkpoints + heartbeat detection:
-  PYTHONPATH=src python -m repro.launch.serve --cluster --fleet 2x1.0 \
-      --requests 10 --chaos 3 --chaos-rate 0.05 --checkpoint-every 3
+  PYTHONPATH=src python -m repro.launch.serve --reduced --cluster \
+      --fleet 2x1.0 --requests 10 --chaos 3 --chaos-rate 0.05 \
+      --checkpoint-every 3
 """
 
 from __future__ import annotations
@@ -23,6 +26,7 @@ import time
 import jax
 
 from repro.configs import ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_zoo as zoo
 from repro.serving.engine import ServingEngine
 
@@ -243,7 +247,9 @@ def run_cluster(args, cfg, params):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b", choices=sorted(ARCHS))
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="CPU-scale reduced config (default: published "
+                         "widths)")
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=64)
     ap.add_argument("--requests", type=int, default=8)
@@ -328,10 +334,12 @@ def main():
                          "trace:<file>")
     args = ap.parse_args()
 
-    cfg = ARCHS[args.arch]
+    # compute runs in bf16 either way; bf16 storage halves the weights
+    cfg = ARCHS[args.arch].with_(param_dtype="bfloat16")
     if args.reduced:
         cfg = cfg.reduced()
-    params = zoo.init_state(cfg, jax.random.PRNGKey(args.seed)).params
+    enable_compile_cache()
+    params = zoo.init_params(cfg, jax.random.PRNGKey(args.seed))
     if args.cluster:
         run_cluster(args, cfg, params)
     else:

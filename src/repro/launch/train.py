@@ -14,6 +14,7 @@ CLI (CPU-scale):
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -25,6 +26,7 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.checkpointing import InMemoryStore, make_store
 from repro.core.elastic import ElasticRuntime, RescaleEvent
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.sharding import ShardingRules, use_rules
 from repro.launch.specs import (batch_shardings, metrics_shardings,
@@ -50,7 +52,12 @@ class ElasticTrainer:
         self.metrics_log: List[Dict[str, float]] = []
         n_devices = n_devices or len(jax.devices())
         self.model_par = model_par
-        init = zoo.init_state(cfg, jax.random.PRNGKey(seed))
+
+        def init_fn(shardings):
+            # each device draws only its own shards of the state
+            init = jax.jit(functools.partial(zoo.init_state, cfg),
+                           out_shardings=shardings)
+            return init(jax.random.PRNGKey(seed))
 
         def mesh_factory(n):
             return _mesh_for(n, self.model_par)
@@ -67,21 +74,17 @@ class ElasticTrainer:
                              out_shardings=(ssh, metrics_shardings(rules)),
                              donate_argnums=(0,))
             # eager AOT compile: this is the paper's 'restart' stage --
-            # application startup dominates rescale cost (Fig 5/6)
+            # application startup dominates rescale cost (Fig 5/6).  The
+            # steps run this executable, so nothing compiles twice.
             with mesh, use_rules(rules):
-                jitted.lower(zoo.abstract_state(cfg),
-                             zoo.batch_spec(cfg, shape)).compile()
-
-            def wrapped(state, batch):
-                with mesh, use_rules(rules):
-                    return jitted(state, batch)
-            return wrapped
+                return jitted.lower(zoo.abstract_state(cfg),
+                                    zoo.batch_spec(cfg, shape)).compile()
 
         self.runtime = ElasticRuntime(
             mesh_factory=mesh_factory,
             shardings_factory=shardings_factory,
             step_factory=step_factory,
-            init_state=init,
+            init_fn=init_fn,
             n_devices=n_devices,
             store=make_store(store_kind),
         )
@@ -135,6 +138,7 @@ def main():
     shape = SHAPES[args.shape]
     if args.reduced:
         cfg, shape = cfg.reduced(), shape.reduced()
+    enable_compile_cache()
     trainer = ElasticTrainer(cfg, shape, n_devices=args.n_devices,
                              model_par=args.model_par, seed=args.seed)
     out = trainer.train(args.steps)

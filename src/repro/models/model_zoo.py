@@ -18,8 +18,7 @@ from repro.launch.sharding import constrain
 from repro.models import layers as L
 from repro.models import mamba2 as ssm_lib
 from repro.models import transformer as T
-from repro.models.schema import (abstract_params, count_params, init_params,
-                                 param_logical_axes)
+from repro.models import schema
 from repro.optim import adamw
 
 
@@ -133,22 +132,26 @@ class TrainState(NamedTuple):
     opt: adamw.AdamWState
 
 
+def init_params(cfg: ModelConfig, rng):
+    """Model params only (what serving needs: no optimizer state)."""
+    return schema.init_params(T.model_schema(cfg), rng, cfg.param_dtype)
+
+
 def init_state(cfg: ModelConfig, rng) -> TrainState:
-    sch = T.model_schema(cfg)
-    params = init_params(sch, rng, cfg.param_dtype)
+    params = init_params(cfg, rng)
     return TrainState(jnp.zeros((), jnp.int32), params, adamw.init(params))
 
 
 def abstract_state(cfg: ModelConfig) -> TrainState:
     sch = T.model_schema(cfg)
-    params = abstract_params(sch, cfg.param_dtype)
+    params = schema.abstract_params(sch, cfg.param_dtype)
     return TrainState(
         jax.ShapeDtypeStruct((), jnp.int32), params,
         adamw.abstract_init(params))
 
 
 def num_params(cfg: ModelConfig) -> int:
-    return count_params(T.model_schema(cfg))
+    return schema.count_params(T.model_schema(cfg))
 
 
 def active_params(cfg: ModelConfig) -> int:

@@ -197,8 +197,7 @@ def _moe_explicit_ep(p, x, cfg: ModelConfig, rules, msize: int):
         return out.reshape(b_loc, s, d), aux
 
     wspec = P("model", None, None)
-    from repro.core.compat import shard_map
-    sm = shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(), wspec, wspec, wspec),
         out_specs=(P(batch_axes, None, None), P()),

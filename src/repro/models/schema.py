@@ -15,6 +15,7 @@ From one schema we derive: real initialized params (smoke tests / training),
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -48,7 +49,14 @@ def _fan_in(spec: Spec) -> int:
     return max(fan, 1)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 2))
 def init_one(spec: Spec, key: jax.Array, dtype) -> jax.Array:
+    """One initialized leaf, made on the device in its target dtype.
+
+    Jitted so the float32 draw, the scale and the cast fuse into one
+    program whose only buffer is the leaf: a stacked bf16 leaf never has
+    a float32 twin of its whole size.
+    """
     dt = jnp.dtype(spec.dtype or dtype)
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, dt)
