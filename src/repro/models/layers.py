@@ -259,28 +259,33 @@ def paged_decode_attention(p, x, cfg, *, pool_k, pool_v, block_tables,
     from repro.kernels.paged_attention import paged_attention
     dt = jnp.dtype(cfg.compute_dtype)
     b, s, _ = x.shape  # s == 1
-    h = rms_norm(x, p["norm"], cfg.norm_eps).astype(dt)
-    q = jnp.einsum("bsd,dhk->bshk", h, p["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, p["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, p["wv"].astype(dt))
-    cos, sin = rotary_embedding(cache_len[:, None], cfg.head_dim,
-                                cfg.rope_theta)
-    q = apply_rotary(q, cos, sin)
-    k = apply_rotary(k, cos, sin)
-    nb, bs = pool_k.shape[0], pool_k.shape[1]
-    bidx = jnp.arange(b)
-    blk = block_tables[bidx, cache_len // bs]
-    off = cache_len % bs
-    if active is not None:
-        blk = jnp.where(active, blk, nb)   # OOB -> write dropped
-    k_new = k[:, 0].astype(pool_k.dtype)
-    v_new = v[:, 0].astype(pool_v.dtype)
-    pool_k = pool_k.at[blk, off].set(k_new, mode="drop")
-    pool_v = pool_v.at[blk, off].set(v_new, mode="drop")
-    out = paged_attention(q[:, 0], pool_k.astype(dt), pool_v.astype(dt),
-                          block_tables, cache_len + 1, impl=impl)[:, None]
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
-    return x + out, (pool_k, pool_v)
+    with jax.named_scope("qkv"):
+        h = rms_norm(x, p["norm"], cfg.norm_eps).astype(dt)
+        q = jnp.einsum("bsd,dhk->bshk", h, p["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", h, p["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", h, p["wv"].astype(dt))
+        cos, sin = rotary_embedding(cache_len[:, None], cfg.head_dim,
+                                    cfg.rope_theta)
+        q = apply_rotary(q, cos, sin)
+        k = apply_rotary(k, cos, sin)
+    with jax.named_scope("kv_write"):
+        nb, bs = pool_k.shape[0], pool_k.shape[1]
+        bidx = jnp.arange(b)
+        blk = block_tables[bidx, cache_len // bs]
+        off = cache_len % bs
+        if active is not None:
+            blk = jnp.where(active, blk, nb)   # OOB -> write dropped
+        k_new = k[:, 0].astype(pool_k.dtype)
+        v_new = v[:, 0].astype(pool_v.dtype)
+        pool_k = pool_k.at[blk, off].set(k_new, mode="drop")
+        pool_v = pool_v.at[blk, off].set(v_new, mode="drop")
+    with jax.named_scope("attention"):
+        out = paged_attention(q[:, 0], pool_k.astype(dt), pool_v.astype(dt),
+                              block_tables, cache_len + 1,
+                              impl=impl)[:, None]
+    with jax.named_scope("attn_out"):
+        out = x + jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+    return out, (pool_k, pool_v)
 
 
 def paged_chunk_attention(p, x, cfg, *, pool_k, pool_v, bt_row, off,
@@ -309,43 +314,52 @@ def paged_chunk_attention(p, x, cfg, *, pool_k, pool_v, bt_row, off,
     """
     dt = jnp.dtype(cfg.compute_dtype)
     b, c, _ = x.shape  # b == 1
-    h = rms_norm(x, p["norm"], cfg.norm_eps).astype(dt)
-    q = jnp.einsum("bsd,dhk->bshk", h, p["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, p["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, p["wv"].astype(dt))
-    positions = off + jnp.arange(c)
-    cos, sin = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
-    q = apply_rotary(q, cos, sin)
-    k = apply_rotary(k, cos, sin)
+    with jax.named_scope("qkv"):
+        h = rms_norm(x, p["norm"], cfg.norm_eps).astype(dt)
+        q = jnp.einsum("bsd,dhk->bshk", h, p["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", h, p["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", h, p["wv"].astype(dt))
+        positions = off + jnp.arange(c)
+        cos, sin = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
+        q = apply_rotary(q, cos, sin)
+        k = apply_rotary(k, cos, sin)
     nb, bs = pool_k.shape[0], pool_k.shape[1]
     if not history:
-        out = full_attention(q, k.astype(dt), v.astype(dt), causal=True)
-        out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
-        n_blk = -(-c // bs)
-        pad = ((0, n_blk * bs - c), (0, 0), (0, 0))
-        rows_k = jnp.pad(k[0].astype(pool_k.dtype), pad)
-        rows_v = jnp.pad(v[0].astype(pool_v.dtype), pad)
-        pool_k = pool_k.at[bt_row[:n_blk]].set(
-            rows_k.reshape(n_blk, bs, *pool_k.shape[2:]), mode="drop")
-        pool_v = pool_v.at[bt_row[:n_blk]].set(
-            rows_v.reshape(n_blk, bs, *pool_v.shape[2:]), mode="drop")
-        return x + out, pool_k, pool_v
-    mb = bt_row.shape[0]
-    bt = jnp.clip(bt_row, 0, nb - 1)
-    rows_k = pool_k[bt].reshape(1, mb * bs, *pool_k.shape[2:])
-    rows_v = pool_v[bt].reshape(1, mb * bs, *pool_v.shape[2:])
-    rows_k = jax.lax.dynamic_update_slice(
-        rows_k, k.astype(rows_k.dtype), (0, off, 0, 0))
-    rows_v = jax.lax.dynamic_update_slice(
-        rows_v, v.astype(rows_v.dtype), (0, off, 0, 0))
-    out = full_attention(q, rows_k.astype(dt), rows_v.astype(dt),
-                         causal=True, q_offset=off)
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
-    pool_k = pool_k.at[bt_row].set(
-        rows_k.reshape(mb, bs, *pool_k.shape[2:]), mode="drop")
-    pool_v = pool_v.at[bt_row].set(
-        rows_v.reshape(mb, bs, *pool_v.shape[2:]), mode="drop")
-    return x + out, pool_k, pool_v
+        with jax.named_scope("attention"):
+            out = full_attention(q, k.astype(dt), v.astype(dt), causal=True)
+        with jax.named_scope("attn_out"):
+            out = x + jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+        with jax.named_scope("kv_write"):
+            n_blk = -(-c // bs)
+            pad = ((0, n_blk * bs - c), (0, 0), (0, 0))
+            rows_k = jnp.pad(k[0].astype(pool_k.dtype), pad)
+            rows_v = jnp.pad(v[0].astype(pool_v.dtype), pad)
+            pool_k = pool_k.at[bt_row[:n_blk]].set(
+                rows_k.reshape(n_blk, bs, *pool_k.shape[2:]), mode="drop")
+            pool_v = pool_v.at[bt_row[:n_blk]].set(
+                rows_v.reshape(n_blk, bs, *pool_v.shape[2:]), mode="drop")
+        return out, pool_k, pool_v
+    # the slot's history view, with the chunk's kv in place, is what
+    # attention reads: its gather and update belong to ``attention``
+    with jax.named_scope("attention"):
+        mb = bt_row.shape[0]
+        bt = jnp.clip(bt_row, 0, nb - 1)
+        rows_k = pool_k[bt].reshape(1, mb * bs, *pool_k.shape[2:])
+        rows_v = pool_v[bt].reshape(1, mb * bs, *pool_v.shape[2:])
+        rows_k = jax.lax.dynamic_update_slice(
+            rows_k, k.astype(rows_k.dtype), (0, off, 0, 0))
+        rows_v = jax.lax.dynamic_update_slice(
+            rows_v, v.astype(rows_v.dtype), (0, off, 0, 0))
+        out = full_attention(q, rows_k.astype(dt), rows_v.astype(dt),
+                             causal=True, q_offset=off)
+    with jax.named_scope("attn_out"):
+        out = x + jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+    with jax.named_scope("kv_write"):
+        pool_k = pool_k.at[bt_row].set(
+            rows_k.reshape(mb, bs, *pool_k.shape[2:]), mode="drop")
+        pool_v = pool_v.at[bt_row].set(
+            rows_v.reshape(mb, bs, *pool_v.shape[2:]), mode="drop")
+    return out, pool_k, pool_v
 
 
 # ----------------------------------------------------------------------------- MLP

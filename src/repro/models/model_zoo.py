@@ -556,29 +556,32 @@ def make_decode_loop(cfg: ModelConfig, shape: ShapeConfig, n_steps: int,
             state, s = carry
             logits, state = serve_step(
                 params, state, {"tokens": s.next_tok, "active": s.active})
-            last = logits[:, -1, :]
-            rng = s.rng
-            if temperature > 0:
-                rng, sub = jax.random.split(rng)
-                sampled = jax.random.categorical(
-                    sub, last.astype(jnp.float32) / temperature, axis=-1)
-            else:
-                sampled = jnp.argmax(last, axis=-1)
-            sampled = sampled.astype(jnp.int32)
-            act = s.active > 0
-            fed2 = s.fed + s.active
-            generating = act & (fed2 >= s.plen)
-            oi = jnp.clip(fed2 - s.plen, 0, S - 1)
-            out_buf = s.out_buf.at[bidx, oi].set(
-                jnp.where(generating, sampled, s.out_buf[bidx, oi]))
-            nxt = jnp.where(fed2 < s.plen,
-                            prompt_buf[bidx, jnp.clip(fed2, 0, S - 1)],
-                            sampled)
-            next_tok = jnp.where(act[:, None], nxt[:, None], s.next_tok)
-            done = generating & (fed2 >= s.maxfed)
-            if eos_token is not None:
-                done = done | (generating & (sampled == eos_token))
-            active = s.active * (1 - done.astype(jnp.int32))
+            with jax.named_scope("sample"):
+                last = logits[:, -1, :]
+                rng = s.rng
+                if temperature > 0:
+                    rng, sub = jax.random.split(rng)
+                    sampled = jax.random.categorical(
+                        sub, last.astype(jnp.float32) / temperature, axis=-1)
+                else:
+                    sampled = jnp.argmax(last, axis=-1)
+                sampled = sampled.astype(jnp.int32)
+                act = s.active > 0
+                fed2 = s.fed + s.active
+                generating = act & (fed2 >= s.plen)
+                oi = jnp.clip(fed2 - s.plen, 0, S - 1)
+                out_buf = s.out_buf.at[bidx, oi].set(
+                    jnp.where(generating, sampled,
+                              s.out_buf[bidx, oi]))
+                nxt = jnp.where(fed2 < s.plen,
+                                prompt_buf[bidx, jnp.clip(fed2, 0, S - 1)],
+                                sampled)
+                next_tok = jnp.where(act[:, None], nxt[:, None],
+                                     s.next_tok)
+                done = generating & (fed2 >= s.maxfed)
+                if eos_token is not None:
+                    done = done | (generating & (sampled == eos_token))
+                active = s.active * (1 - done.astype(jnp.int32))
             return (state, SampleState(next_tok, active, fed2, s.plen,
                                        s.maxfed, out_buf, rng)), ()
 
@@ -797,7 +800,8 @@ def make_paged_serve_step(cfg: ModelConfig, shape: ShapeConfig,
                     from repro.models import moe as moe_lib
                     x, _ = moe_lib.moe_block(lp["moe"], x, cfg)
                 else:
-                    x = L.swiglu_block(lp["mlp"], x, cfg)
+                    with jax.named_scope("mlp"):
+                        x = L.swiglu_block(lp["mlp"], x, cfg)
                 return x, (pk, pv)
             h, (ks, vs) = _scan(
                 body, h, (params["layers"], cache["k"], cache["v"]),
@@ -828,7 +832,8 @@ def make_paged_serve_step(cfg: ModelConfig, shape: ShapeConfig,
                 x, (pk, pv) = L.paged_decode_attention(
                     shared["attn"], x, cfg, pool_k=pk, pool_v=pv,
                     block_tables=bt, cache_len=clen, active=act, impl=impl)
-                x = L.swiglu_block(shared["mlp"], x, cfg)
+                with jax.named_scope("mlp"):
+                    x = L.swiglu_block(shared["mlp"], x, cfg)
                 return x, (st, cs, pk, pv)
             h, (ssm, conv, ks, vs) = _scan(
                 period_body, h,
@@ -838,7 +843,8 @@ def make_paged_serve_step(cfg: ModelConfig, shape: ShapeConfig,
         else:
             raise ValueError(cfg.family)
 
-        logits = T.lm_logits(params, h, cfg)
+        with jax.named_scope("logits"):
+            logits = T.lm_logits(params, h, cfg)
         return logits, PagedDecodeState(new_cache, clen + active, bt)
 
     return serve_step
@@ -907,7 +913,8 @@ def make_paged_bulk_prefill(cfg: ModelConfig, shape: ShapeConfig,
                     from repro.models import moe as moe_lib
                     x, _ = moe_lib.moe_block(lp["moe"], x, cfg)
                 else:
-                    x = L.swiglu_block(lp["mlp"], x, cfg)
+                    with jax.named_scope("mlp"):
+                        x = L.swiglu_block(lp["mlp"], x, cfg)
                 return x, (pk, pv)
             _, (ks, vs) = jax.lax.scan(
                 body, h, (params["layers"], cache["k"], cache["v"]))
@@ -954,7 +961,8 @@ def make_paged_bulk_prefill(cfg: ModelConfig, shape: ShapeConfig,
                 x, pk, pv = L.paged_chunk_attention(
                     shared["attn"], x, cfg, pool_k=pk, pool_v=pv,
                     bt_row=bt_row, off=off, history=not first_chunk)
-                x = L.swiglu_block(shared["mlp"], x, cfg)
+                with jax.named_scope("mlp"):
+                    x = L.swiglu_block(shared["mlp"], x, cfg)
                 return x, (sts, cvs, pk, pv)
             _, (ssm, conv, ks, vs) = jax.lax.scan(
                 period_body, h,

@@ -43,6 +43,16 @@ The hot path is built around three properties:
   prefill-with-history.  Token streams are bit-identical to the dense
   engine — paged and dense loops share the exact sampling body and the
   attention cores agree bit-for-bit (asserted in tests).
+* **Observable** — the host work of each window runs under
+  ``jax.profiler.TraceAnnotation`` spans (``serve.admit``,
+  ``serve.prefill``, ``serve.decode_dispatch``, ``serve.poll`` and
+  ``serve.host_sync``), one per admission or window and never per
+  token: in a profiler trace they sit on the host plane, on the clock
+  of the device's runs, so a device-idle gap can be named after what
+  the host was doing.  With no trace active they cost next to nothing.
+  ``program_hlo()`` returns the optimized HLO of every program the
+  engine has dispatched, whose ``op_name`` metadata carries the
+  model's named scopes (``models/``).
 * **Migratable work units** — ``pack()`` captures each occupied slot
   (request progress + that slot's KV/state cache columns, as host
   arrays) into a self-contained ``WorkUnit``; ``unpack()`` admits units
@@ -66,6 +76,8 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import model_zoo as zoo
+
+span = jax.profiler.TraceAnnotation
 
 # Padded prompt-chunk sizes for bulk prefill.  Ascending; buckets larger
 # than the engine's cache are dropped at construction.  One compiled
@@ -375,6 +387,11 @@ class ServingEngine:
         self.resize_evictions = 0   # slots evicted (paused) by a shrink
         self._peak_slots = 0        # high-water concurrent occupied slots
         self._chunk_tokens_pending = 0
+        # name -> (jitted program, arguments after params and state) of
+        # each program dispatched at the current geometry; None stands
+        # for the live (sample, prompt_buf), which the decode loop
+        # donates.  ``program_hlo`` lowers from here.
+        self._programs: Dict[str, Tuple[Any, Optional[tuple]]] = {}
         if prefill_mode == "chunked" and cfg.family in zoo.BULK_PREFILL_FAMILIES:
             self._buckets = tuple(sorted(
                 c for c in prefill_buckets if 0 < c <= max_seq))
@@ -457,6 +474,31 @@ class ServingEngine:
                 self._alloc.peak_in_use if self._alloc else 0,
             "pool_blocks": self.pool_blocks,
         }
+
+    def program_hlo(self) -> Dict[str, str]:
+        """Optimized HLO text of each program dispatched since the
+        engine's geometry was last set: the decode window
+        (``decode_loop.k<steps>``) and every prefill chunk program
+        (``paged_prefill.c<bucket>[.first]`` or ``bulk_prefill.c<bucket>``).
+
+        Each is lowered with the engine's own arguments, so jit's cache
+        hands back the executable the dispatches ran and nothing is
+        compiled again.  Instructions carry the model's named scopes in
+        their ``op_name`` metadata, which maps a device op in a profiler
+        trace (named by its instruction) to the part of the model it
+        belongs to.
+        """
+        out = {}
+        for name, (fn, rest) in self._programs.items():
+            if rest is None:
+                rest = (self.sample, self._prompt_buf)
+            out[name] = fn.lower(self.params, self.state,
+                                 *rest).compile().as_text()
+        return out
+
+    def _note_program(self, name: str, fn, rest: Optional[tuple]):
+        if name not in self._programs:
+            self._programs[name] = (fn, rest)
 
     # ----------------------------------------------------- block lifecycle
     def _req_maxfed(self, req: Request) -> int:
@@ -611,17 +653,20 @@ class ServingEngine:
             self._write_table_row(slot, blocks)
             n_fed = self._paged_chunk_prefills(req, slot, 0, P - 1)
         else:
-            chunk, n_real = self._pick_chunk(P - 1)
-            if chunk:
-                bulk = _shared_bulk_prefill(self.cfg, self.shape, chunk)
-                ctoks = np.zeros((1, chunk), np.int32)
-                ctoks[0, :n_real] = req.prompt[:n_real]
-                self.state = bulk(self.params, self.state,
-                                  jnp.asarray(ctoks), np.int32(slot),
-                                  np.int32(n_real))
-                self.chunk_prefills += 1
-                self._chunk_tokens_pending += n_real
-            else:
+            with span("serve.prefill"):
+                chunk, n_real = self._pick_chunk(P - 1)
+                if chunk:
+                    bulk = _shared_bulk_prefill(self.cfg, self.shape, chunk)
+                    ctoks = np.zeros((1, chunk), np.int32)
+                    ctoks[0, :n_real] = req.prompt[:n_real]
+                    args = (jnp.asarray(ctoks), np.int32(slot),
+                            np.int32(n_real))
+                    self._note_program(f"bulk_prefill.c{chunk}", bulk,
+                                       args)
+                    self.state = bulk(self.params, self.state, *args)
+                    self.chunk_prefills += 1
+                    self._chunk_tokens_pending += n_real
+            if not chunk:
                 self._set_cache_len(slot, 0)
             n_fed = n_real
         self._slots[slot] = req
@@ -643,23 +688,27 @@ class ServingEngine:
         bucket fits the (remaining, room) pair the leftover streams.
         """
         off, remaining = start, n_prefill
-        while remaining > 0:
-            chunk, n_real = self._pick_chunk(remaining,
-                                             room=self.max_seq - off)
-            if not chunk:
-                break
-            bulk = _shared_paged_prefill(self.cfg, self.shape, chunk,
-                                         self.block_size, self.pool_blocks,
-                                         first=(off == 0))
-            ctoks = np.zeros((1, chunk), np.int32)
-            ctoks[0, :n_real] = req.prompt[off:off + n_real]
-            self.state = bulk(self.params, self.state,
-                              jnp.asarray(ctoks), np.int32(slot),
-                              np.int32(off), np.int32(n_real))
-            self.chunk_prefills += 1
-            self._chunk_tokens_pending += n_real
-            off += n_real
-            remaining -= n_real
+        with span("serve.prefill"):
+            while remaining > 0:
+                chunk, n_real = self._pick_chunk(remaining,
+                                                 room=self.max_seq - off)
+                if not chunk:
+                    break
+                bulk = _shared_paged_prefill(
+                    self.cfg, self.shape, chunk, self.block_size,
+                    self.pool_blocks, first=(off == 0))
+                ctoks = np.zeros((1, chunk), np.int32)
+                ctoks[0, :n_real] = req.prompt[off:off + n_real]
+                args = (jnp.asarray(ctoks), np.int32(slot), np.int32(off),
+                        np.int32(n_real))
+                self._note_program(
+                    f"paged_prefill.c{chunk}" + (".first" if off == 0
+                                                 else ""), bulk, args)
+                self.state = bulk(self.params, self.state, *args)
+                self.chunk_prefills += 1
+                self._chunk_tokens_pending += n_real
+                off += n_real
+                remaining -= n_real
         if off == start:
             self._set_cache_len(slot, start)
         return off
@@ -738,23 +787,26 @@ class ServingEngine:
 
     def _admit(self):
         """Fill free slots from the restore queue, then the request queue."""
-        for slot in range(self.batch):
-            if self._slots[slot] is not None:
-                continue
-            if self._restore:
-                if not self._can_admit(self._restore[0].snapshot.request):
-                    break
-                u = self._restore.pop(0)
-                self._install(u.snapshot, slot)
-                # keep the unit's identity alive on the slot: a later
-                # pack() re-emits the SAME uid and extends the same hop
-                # history (the list object is shared, so provenance
-                # recorded while the slot runs lands on the right unit)
-                self._unit_meta[slot] = (u.uid, u.hops, u.origin)
-            elif self._queue:
-                if not self._can_admit(self._queue[0]):
-                    break
-                self._admit_fresh(self._queue.pop(0), slot)
+        with span("serve.admit"):
+            for slot in range(self.batch):
+                if self._slots[slot] is not None:
+                    continue
+                if self._restore:
+                    head = self._restore[0].snapshot.request
+                    if not self._can_admit(head):
+                        break
+                    u = self._restore.pop(0)
+                    self._install(u.snapshot, slot)
+                    # keep the unit's identity alive on the slot: a later
+                    # pack() re-emits the SAME uid and extends the same
+                    # hop history (the list object is shared, so
+                    # provenance recorded while the slot runs lands on
+                    # the right unit)
+                    self._unit_meta[slot] = (u.uid, u.hops, u.origin)
+                elif self._queue:
+                    if not self._can_admit(self._queue[0]):
+                        break
+                    self._admit_fresh(self._queue.pop(0), slot)
         self._peak_slots = max(self._peak_slots, self.n_active)
 
     # ------------------------------------------------------------- stepping
@@ -784,8 +836,10 @@ class ServingEngine:
         else:
             loop = _shared_loop(self.cfg, self.shape, n_steps,
                                 self.temperature, self.eos_token)
-        self.state, self.sample = loop(self.params, self.state,
-                                       self.sample, self._prompt_buf)
+        self._note_program(f"decode_loop.k{n_steps}", loop, None)
+        with span("serve.decode_dispatch"):
+            self.state, self.sample = loop(self.params, self.state,
+                                           self.sample, self._prompt_buf)
         stats["steps"] = n_steps
         if self.eos_token is not None:
             # EOS can end a slot at any inner step, invisibly to the host
@@ -838,7 +892,8 @@ class ServingEngine:
     def _fetch(self, tree):
         """The ONLY device->host path in the engine (counted)."""
         self.host_syncs += 1
-        return jax.device_get(tree)
+        with span("serve.host_sync"):
+            return jax.device_get(tree)
 
     def _poll(self):
         """Materialize device progress into the Request objects.
@@ -846,31 +901,35 @@ class ServingEngine:
         Called when the projection says a slot completed, and at drains —
         never in the steady-state decode loop.
         """
-        occupied = [i for i, r in enumerate(self._slots) if r is not None]
-        if not occupied:
-            return
-        out_buf, fed, next_tok, active = self._fetch(
-            (self.sample.out_buf, self.sample.fed, self.sample.next_tok,
-             self.sample.active))
-        for slot in occupied:
-            req = self._slots[slot]
-            self._fed[slot] = int(fed[slot])
-            self._next_tok_host[slot] = int(next_tok[slot, 0])
-            n = max(0, int(fed[slot]) - int(self._plen[slot]) + 1)
-            new = out_buf[slot, int(self._out_read[slot]):n]
-            req.out_tokens.extend(int(t) for t in new)
-            self._out_read[slot] = n
-            # a device-deactivated occupied slot is finished — either it
-            # reached maxfed, or it sampled the EOS token and early-exited
-            if fed[slot] >= self._maxfed[slot] or int(active[slot]) == 0:
-                req.done = True
-                self._completed.append(req)
-                self._slots[slot] = None
-                self._unit_meta.pop(slot, None)
-                if self._alloc is not None:
-                    # blocks return to the pool at the window boundary;
-                    # the next _admit can hand them to a queued request
-                    self._release_blocks(slot)
+        with span("serve.poll"):
+            occupied = [i for i, r in enumerate(self._slots)
+                        if r is not None]
+            if not occupied:
+                return
+            out_buf, fed, next_tok, active = self._fetch(
+                (self.sample.out_buf, self.sample.fed,
+                 self.sample.next_tok, self.sample.active))
+            for slot in occupied:
+                req = self._slots[slot]
+                self._fed[slot] = int(fed[slot])
+                self._next_tok_host[slot] = int(next_tok[slot, 0])
+                n = max(0, int(fed[slot]) - int(self._plen[slot]) + 1)
+                new = out_buf[slot, int(self._out_read[slot]):n]
+                req.out_tokens.extend(int(t) for t in new)
+                self._out_read[slot] = n
+                # a device-deactivated occupied slot is finished — either
+                # it reached maxfed, or it sampled the EOS token and
+                # early-exited
+                if (fed[slot] >= self._maxfed[slot]
+                        or int(active[slot]) == 0):
+                    req.done = True
+                    self._completed.append(req)
+                    self._slots[slot] = None
+                    self._unit_meta.pop(slot, None)
+                    if self._alloc is not None:
+                        # blocks return to the pool at the window boundary;
+                        # the next _admit can hand them to a queued request
+                        self._release_blocks(slot)
 
     # ----------------------------------------------- WorkUnit pack/unpack
     #
@@ -1159,6 +1218,7 @@ class ServingEngine:
         self._maxfed = np.zeros(new_batch, np.int64)
         self._next_tok_host = np.zeros(new_batch, np.int64)
         self._out_read = np.zeros(new_batch, np.int64)
+        self._programs = {}
         # survivors re-admit ahead of everything already waiting
         self._restore = keep + self._restore
         for u in evicted:
