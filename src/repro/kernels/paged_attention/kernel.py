@@ -10,17 +10,20 @@ BlockSpec index map reads ``block_tables[lane, j]`` to pull the j-th
 logical block's *physical* pool row into VMEM — no gather
 materialization.
 
-Each grid step moves one whole pool row, ``(bs, KV, D)``: the block's
-last two dims equal the pool's, which is what Mosaic's tiling rule
-accepts, and one DMA per block serves every kv head.  The kernel then
-walks the kv heads with static slices; GQA lays q out as (B, KV, G, D)
+Pool rows are lane-dense: ``(bs, KV*D)``, every kv head's D values side
+by side on the minor axis, so a row fills whole 128-lane tiles even where
+D is 64 and the device stores the pool row-major, unpadded.  Each grid
+step moves one whole pool row: the block's last two dims equal the
+pool's, which is what Mosaic's tiling rule accepts, and one DMA per block
+serves every kv head.  The kernel then walks the kv heads, head ``h`` as
+the static lane slice ``[h*D, (h+1)*D)``; GQA lays q out as (B, KV, G, D)
 so each head's whole query group is one (G, D) tile.
 
 Blocks wholly past ``kv_len`` are skipped with ``pl.when`` (true block
 skipping); the partial tail block masks positions >= kv_len to an
 exact-zero softmax weight.
 
-VMEM working set: q(KV,G,d) + k,v(bs,KV,d) + acc(KV,G,d)f32 +
+VMEM working set: q(KV,G,d) + k,v(bs,KV*d) + acc(KV,G,d)f32 +
 m,l(KV,G,1)f32 — tiny; the pool itself stays in HBM and only
 table-addressed blocks move.
 """
@@ -50,13 +53,15 @@ def _paged_kernel(bt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     klen = kl_ref[ib]
+    d = q_ref.shape[-1]
 
     @pl.when(j * block_size < klen)
     def _body():
         for h in range(q_ref.shape[1]):
             q = q_ref[0, h].astype(jnp.float32)          # (G, d)
-            k = k_ref[0, :, h, :].astype(jnp.float32)    # (bs, d)
-            v = v_ref[0, :, h, :].astype(jnp.float32)
+            lanes = slice(h * d, (h + 1) * d)
+            k = k_ref[0, :, lanes].astype(jnp.float32)   # (bs, d)
+            v = v_ref[0, :, lanes].astype(jnp.float32)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = s * scale
@@ -81,12 +86,13 @@ def _paged_kernel(bt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_attention(q, k_pool, v_pool, block_tables, kv_len, *,
                     interpret: bool = False):
-    """q: (B, H, D); pools: (num_blocks, bs, KV, D); block_tables:
+    """q: (B, H, D); pools: (num_rows, bs, KV*D); block_tables:
     (B, max_blocks) int32 physical pool rows (pre-clamped into range);
     kv_len: (B,) int32 valid positions per lane.  Returns (B, H, D).
     """
     b, h, d = q.shape
-    nb, bs, kv, _ = k_pool.shape
+    _, bs, kvd = k_pool.shape
+    kv = kvd // d
     group = h // kv
     max_blocks = block_tables.shape[1]
     scale = d ** -0.5
@@ -94,8 +100,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_len, *,
 
     kernel = functools.partial(_paged_kernel, block_size=bs, scale=scale)
     lane = pl.BlockSpec((1, kv, group, d), lambda ib, j, bt, kl: (ib, 0, 0, 0))
-    pool_row = pl.BlockSpec((1, bs, kv, d),
-                            lambda ib, j, bt, kl: (bt[ib, j], 0, 0, 0))
+    pool_row = pl.BlockSpec((1, bs, kvd),
+                            lambda ib, j, bt, kl: (bt[ib, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, max_blocks),

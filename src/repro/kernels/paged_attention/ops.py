@@ -22,18 +22,21 @@ from repro.kernels.paged_attention.ref import gather_pages, \
     paged_attention_ref
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 @functools.partial(jax.jit, static_argnames=("impl",))
 def paged_attention(q, k_pool, v_pool, block_tables, kv_len, *,
                     impl: str = "auto"):
-    """q: (B, H, D); pools: (num_blocks, bs, KV, D); block_tables:
-    (B, max_blocks) int32 (sentinel entries allowed — clamped here);
-    kv_len: (B,) int32.  Returns (B, H, D)."""
+    """q: (B, H, D); pools: (num_rows, bs, KV*D) lane-dense rows;
+    block_tables: (B, max_blocks) int32 pool rows (sentinel entries
+    allowed — clamped here); kv_len: (B,) int32.  Returns (B, H, D)."""
     if impl == "auto":
-        impl = "kernel" if jax.default_backend() == "tpu" else "ref"
+        impl = "kernel" if _on_tpu() else "ref"
     if impl == "ref":
         return paged_attention_ref(q, k_pool, v_pool, block_tables, kv_len)
-    nb = k_pool.shape[0]
-    bt = jnp.clip(block_tables, 0, nb - 1)
-    interpret = jax.default_backend() != "tpu"
+    n_rows = k_pool.shape[0]
+    bt = jnp.clip(block_tables, 0, n_rows - 1)
     return _paged_kernel(q, k_pool, v_pool, bt, kv_len,
-                         interpret=interpret)
+                         interpret=not _on_tpu())

@@ -1,8 +1,9 @@
 """Oracle for the paged-attention kernel: gather + the model's own math.
 
-The reference gathers each lane's blocks (via its block table) into the
-contiguous ``(B, S, KV, D)`` layout the dense cache uses and runs the
-exact ``full_attention`` call from ``layers.decode_attention``.  Because
+The reference gathers each lane's lane-dense ``(bs, KV*D)`` pool rows
+(via its block table), reshapes them into the contiguous
+``(B, S, KV, D)`` layout the dense cache uses and runs the exact
+``full_attention`` call from ``layers.decode_attention``.  Because
 positions at or beyond ``kv_len`` are masked to an exact-zero softmax
 weight, the gathered garbage in unallocated / sentinel blocks
 contributes nothing and the result is *bit-identical* to the dense
@@ -31,10 +32,15 @@ def gather_pages(pool, block_tables):
 
 
 def paged_attention_ref(q, k_pool, v_pool, block_tables, kv_len):
-    """q: (B, H, D) one decode token per lane; pools: (num_blocks, bs,
-    KV, D); block_tables: (B, max_blocks) int32; kv_len: (B,) valid
+    """q: (B, H, D) one decode token per lane; pools: (num_rows, bs,
+    KV*D); block_tables: (B, max_blocks) int32; kv_len: (B,) valid
     positions per lane.  Returns (B, H, D)."""
-    k = gather_pages(k_pool, block_tables)
-    v = gather_pages(v_pool, block_tables)
-    out = full_attention(q[:, None], k, v, causal=False, kv_len=kv_len)
+    d = q.shape[-1]
+
+    def heads(pool):
+        rows = gather_pages(pool, block_tables)      # (B, S, KV*D)
+        return rows.reshape(rows.shape[:2] + (-1, d)).astype(q.dtype)
+
+    out = full_attention(q[:, None], heads(k_pool), heads(v_pool),
+                         causal=False, kv_len=kv_len)
     return out[:, 0]

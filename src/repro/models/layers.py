@@ -239,18 +239,40 @@ def decode_attention(p, x, cfg, *, cache_k, cache_v, cache_len,
     return x + out, (cache_k, cache_v)
 
 
-def paged_decode_attention(p, x, cfg, *, pool_k, pool_v, block_tables,
-                           cache_len, active=None, impl="auto"):
+def _pool_rows(pool):
+    """Stacked pool ``(L, num_blocks, bs, KV*D)`` -> ``(L*num_blocks, bs,
+    KV*D)`` rows: a free reshape of the row-major pool."""
+    return pool.reshape((-1,) + pool.shape[2:])
+
+
+def _read_rows(table, layer, nb):
+    """A layer's block numbers -> rows of the stacked pool, sentinels
+    clamped into the layer (their positions are masked by the caller)."""
+    return layer * nb + jnp.clip(table, 0, nb - 1)
+
+
+def _write_rows(table, layer, nb, n_rows):
+    """A layer's block numbers -> rows of the stacked pool; a sentinel or
+    out-of-range entry goes to ``n_rows``, past the WHOLE stacked pool, so
+    its scatter is dropped (never row 0 of the next layer)."""
+    valid = (table >= 0) & (table < nb)
+    return jnp.where(valid, layer * nb + table, n_rows)
+
+
+def paged_decode_attention(p, x, cfg, *, pool_k, pool_v, layer,
+                           block_tables, cache_len, active=None,
+                           impl="auto"):
     """One-token decode against a *paged* KV cache (block pool + tables).
 
-    pool_k/v: (num_blocks, bs, KV, D) — one shared device pool; each
-    lane's logical positions map through block_tables (B, max_blocks) to
-    physical pool rows.  Writes the new kv at logical position
-    ``cache_len`` (physical: block ``bt[b, cache_len // bs]``, offset
-    ``cache_len % bs``); inactive lanes are routed to an out-of-range
-    index and dropped (``mode="drop"``), the paged analogue of the dense
-    path's keep-old-value masking.  The attention core
-    (``kernels.paged_attention``) is bit-identical to
+    pool_k/v: the stacked pool of every layer, ``(L, num_blocks, bs,
+    KV*D)`` lane-dense rows; ``layer`` (traced) picks this layer's rows.
+    Each lane's logical positions map through block_tables (B,
+    max_blocks) to physical blocks.  Writes the new kv, in place, at
+    logical position ``cache_len`` (physical: block ``bt[b, cache_len //
+    bs]`` of ``layer``, offset ``cache_len % bs``); inactive lanes are
+    routed past the whole pool and dropped (``mode="drop"``), the paged
+    analogue of the dense path's keep-old-value masking.  The attention
+    core (``kernels.paged_attention``) is bit-identical to
     ``decode_attention``'s ``full_attention`` on CPU backends and a
     Pallas kernel on TPU.
 
@@ -268,39 +290,43 @@ def paged_decode_attention(p, x, cfg, *, pool_k, pool_v, block_tables,
                                     cfg.rope_theta)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
+    shape, nb, bs = pool_k.shape, pool_k.shape[1], pool_k.shape[2]
+    rows_k, rows_v = _pool_rows(pool_k), _pool_rows(pool_v)
     with jax.named_scope("kv_write"):
-        nb, bs = pool_k.shape[0], pool_k.shape[1]
-        bidx = jnp.arange(b)
-        blk = block_tables[bidx, cache_len // bs]
-        off = cache_len % bs
+        blk = block_tables[jnp.arange(b), cache_len // bs]
         if active is not None:
-            blk = jnp.where(active, blk, nb)   # OOB -> write dropped
-        k_new = k[:, 0].astype(pool_k.dtype)
-        v_new = v[:, 0].astype(pool_v.dtype)
-        pool_k = pool_k.at[blk, off].set(k_new, mode="drop")
-        pool_v = pool_v.at[blk, off].set(v_new, mode="drop")
+            blk = jnp.where(active, blk, nb)   # sentinel -> write dropped
+        row = _write_rows(blk, layer, nb, rows_k.shape[0])
+        off = cache_len % bs
+        rows_k = rows_k.at[row, off].set(
+            k[:, 0].reshape(b, -1).astype(rows_k.dtype), mode="drop")
+        rows_v = rows_v.at[row, off].set(
+            v[:, 0].reshape(b, -1).astype(rows_v.dtype), mode="drop")
     with jax.named_scope("attention"):
-        out = paged_attention(q[:, 0], pool_k.astype(dt), pool_v.astype(dt),
-                              block_tables, cache_len + 1,
-                              impl=impl)[:, None]
+        out = paged_attention(q[:, 0], rows_k, rows_v,
+                              _read_rows(block_tables, layer, nb),
+                              cache_len + 1, impl=impl)[:, None]
     with jax.named_scope("attn_out"):
         out = x + jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
-    return out, (pool_k, pool_v)
+    return out, (rows_k.reshape(shape), rows_v.reshape(shape))
 
 
-def paged_chunk_attention(p, x, cfg, *, pool_k, pool_v, bt_row, off,
+def paged_chunk_attention(p, x, cfg, *, pool_k, pool_v, layer, bt_row, off,
                           history=True):
     """Chunk prefill over one slot's paged KV blocks.
 
     x: (1, C, d) — C prompt tokens at absolute positions off..off+C-1.
-    bt_row: (max_blocks,) the slot's block table.  Gathers the slot's
-    blocks into a contiguous (1, S_max, KV, D) view, writes the chunk's
-    kv at ``off`` (a block-table append in logical terms), attends
-    causally at ``q_offset=off`` over history + chunk, and scatters the
-    rows back through the table (sentinel entries dropped).  Per-position
-    math matches the streamed decode path bit-for-bit for causal
-    families — masked history/pad positions contribute exact zeros —
-    which is what lets multi-chunk prefill subsume prefill-with-history.
+    pool_k/v: the stacked pool ``(L, num_blocks, bs, KV*D)``; ``layer``
+    (traced) picks this layer's rows.  bt_row: (max_blocks,) the slot's
+    block table.  Gathers the slot's rows into a contiguous (1, S_max,
+    KV, D) view, writes the chunk's kv at ``off`` (a block-table append
+    in logical terms), attends causally at ``q_offset=off`` over history
+    + chunk, and scatters the rows back in place through the table
+    (sentinel entries routed past the whole pool and dropped).
+    Per-position math matches the streamed decode path bit-for-bit for
+    causal families — masked history/pad positions contribute exact
+    zeros — which is what lets multi-chunk prefill subsume
+    prefill-with-history.
 
     ``history=False`` is the first-chunk (``off == 0``) specialization:
     with no history every gathered position is masked, so the gather /
@@ -323,7 +349,9 @@ def paged_chunk_attention(p, x, cfg, *, pool_k, pool_v, bt_row, off,
         cos, sin = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
-    nb, bs = pool_k.shape[0], pool_k.shape[1]
+    shape, nb, bs = pool_k.shape, pool_k.shape[1], pool_k.shape[2]
+    rows_k, rows_v = _pool_rows(pool_k), _pool_rows(pool_v)
+    n_rows, kvd = rows_k.shape[0], rows_k.shape[2]
     if not history:
         with jax.named_scope("attention"):
             out = full_attention(q, k.astype(dt), v.astype(dt), causal=True)
@@ -331,35 +359,36 @@ def paged_chunk_attention(p, x, cfg, *, pool_k, pool_v, bt_row, off,
             out = x + jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
         with jax.named_scope("kv_write"):
             n_blk = -(-c // bs)
-            pad = ((0, n_blk * bs - c), (0, 0), (0, 0))
-            rows_k = jnp.pad(k[0].astype(pool_k.dtype), pad)
-            rows_v = jnp.pad(v[0].astype(pool_v.dtype), pad)
-            pool_k = pool_k.at[bt_row[:n_blk]].set(
-                rows_k.reshape(n_blk, bs, *pool_k.shape[2:]), mode="drop")
-            pool_v = pool_v.at[bt_row[:n_blk]].set(
-                rows_v.reshape(n_blk, bs, *pool_v.shape[2:]), mode="drop")
-        return out, pool_k, pool_v
+            row = _write_rows(bt_row[:n_blk], layer, nb, n_rows)
+            pad = ((0, n_blk * bs - c), (0, 0))
+
+            def blocks(t, dtype):
+                t = jnp.pad(t[0].reshape(c, kvd).astype(dtype), pad)
+                return t.reshape(n_blk, bs, kvd)
+
+            rows_k = rows_k.at[row].set(blocks(k, rows_k.dtype), mode="drop")
+            rows_v = rows_v.at[row].set(blocks(v, rows_v.dtype), mode="drop")
+        return out, rows_k.reshape(shape), rows_v.reshape(shape)
     # the slot's history view, with the chunk's kv in place, is what
     # attention reads: its gather and update belong to ``attention``
     with jax.named_scope("attention"):
         mb = bt_row.shape[0]
-        bt = jnp.clip(bt_row, 0, nb - 1)
-        rows_k = pool_k[bt].reshape(1, mb * bs, *pool_k.shape[2:])
-        rows_v = pool_v[bt].reshape(1, mb * bs, *pool_v.shape[2:])
-        rows_k = jax.lax.dynamic_update_slice(
-            rows_k, k.astype(rows_k.dtype), (0, off, 0, 0))
-        rows_v = jax.lax.dynamic_update_slice(
-            rows_v, v.astype(rows_v.dtype), (0, off, 0, 0))
-        out = full_attention(q, rows_k.astype(dt), rows_v.astype(dt),
+        read = _read_rows(bt_row, layer, nb)
+        hist_k = rows_k[read].reshape(1, mb * bs, -1, cfg.head_dim)
+        hist_v = rows_v[read].reshape(1, mb * bs, -1, cfg.head_dim)
+        hist_k = jax.lax.dynamic_update_slice(
+            hist_k, k.astype(hist_k.dtype), (0, off, 0, 0))
+        hist_v = jax.lax.dynamic_update_slice(
+            hist_v, v.astype(hist_v.dtype), (0, off, 0, 0))
+        out = full_attention(q, hist_k.astype(dt), hist_v.astype(dt),
                              causal=True, q_offset=off)
     with jax.named_scope("attn_out"):
         out = x + jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
     with jax.named_scope("kv_write"):
-        pool_k = pool_k.at[bt_row].set(
-            rows_k.reshape(mb, bs, *pool_k.shape[2:]), mode="drop")
-        pool_v = pool_v.at[bt_row].set(
-            rows_v.reshape(mb, bs, *pool_v.shape[2:]), mode="drop")
-    return out, pool_k, pool_v
+        row = _write_rows(bt_row, layer, nb, n_rows)
+        rows_k = rows_k.at[row].set(hist_k.reshape(mb, bs, kvd), mode="drop")
+        rows_v = rows_v.at[row].set(hist_v.reshape(mb, bs, kvd), mode="drop")
+    return out, rows_k.reshape(shape), rows_v.reshape(shape)
 
 
 # ----------------------------------------------------------------------------- MLP

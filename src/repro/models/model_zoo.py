@@ -692,13 +692,19 @@ def make_serve_step(cfg: ModelConfig, shape: ShapeConfig,
 class PagedDecodeState(NamedTuple):
     """Decode state over a *paged* KV cache (vLLM-style block pool).
 
-    KV leaves are one shared pool ``(..., num_blocks, block_size, KV, D)``
-    instead of dense per-lane columns; each lane addresses its logical
-    positions through ``block_tables`` (B, max_blocks) of physical pool
-    rows.  Unallocated table entries hold the sentinel ``num_blocks``
-    (out of range): gathers clamp it (garbage always masked by kv_len /
-    causality), scatters drop it (``mode="drop"``) — so stale tables can
-    never corrupt live blocks.  Recurrent leaves (ssm/conv) are O(1) per
+    KV leaves are one shared pool ``(L, num_blocks, block_size, KV*D)``
+    (``periods`` in place of ``L`` for the hybrid family) instead of
+    dense per-lane columns.  Rows are lane-dense: every kv head's D values
+    side by side on the minor axis, so the device stores the pool
+    row-major with no padding and no layout conversion, and the layer
+    scans carry the whole pool and update it in place.  Each lane
+    addresses its logical positions through ``block_tables`` (B,
+    max_blocks) of physical pool rows.  Unallocated table entries hold
+    the sentinel ``num_blocks`` (out of range): gathers clamp it into the
+    layer (garbage always masked by kv_len / causality), scatters send it
+    past the whole stacked pool and drop it (``mode="drop"``) — so stale
+    tables can never corrupt live blocks, in this layer or the next.
+    Recurrent leaves (ssm/conv) are O(1) per
     lane and stay lane-indexed.
     """
     cache: Any
@@ -731,7 +737,7 @@ def abstract_paged_decode_state(cfg: ModelConfig, shape: ShapeConfig,
     assert S % block_size == 0, (S, block_size)
     mb = S // block_size
     bf16 = jnp.bfloat16
-    KV, D = cfg.num_kv_heads, cfg.head_dim
+    kv_row = (num_blocks, block_size, cfg.num_kv_heads * cfg.head_dim)
     d_inner, nheads, conv_dim, _ = ssm_lib.mamba2_dims(cfg)
     N, P_ = cfg.ssm_state, cfg.ssm_head_dim
 
@@ -739,8 +745,8 @@ def abstract_paged_decode_state(cfg: ModelConfig, shape: ShapeConfig,
         return jax.ShapeDtypeStruct(shp, dt)
 
     if cfg.family in ("dense", "moe"):
-        cache = {"k": sds((cfg.num_layers, num_blocks, block_size, KV, D)),
-                 "v": sds((cfg.num_layers, num_blocks, block_size, KV, D))}
+        cache = {"k": sds((cfg.num_layers,) + kv_row),
+                 "v": sds((cfg.num_layers,) + kv_row)}
     elif cfg.family == "ssm":
         cache = {"ssm": sds((cfg.num_layers, B, nheads, P_, N), jnp.float32),
                  "conv": sds((cfg.num_layers, B, cfg.conv_width - 1,
@@ -751,8 +757,8 @@ def abstract_paged_decode_state(cfg: ModelConfig, shape: ShapeConfig,
                             jnp.float32),
                  "conv": sds((periods, cfg.attn_every, B,
                               cfg.conv_width - 1, conv_dim)),
-                 "k": sds((periods, num_blocks, block_size, KV, D)),
-                 "v": sds((periods, num_blocks, block_size, KV, D))}
+                 "k": sds((periods,) + kv_row),
+                 "v": sds((periods,) + kv_row)}
     else:
         raise ValueError(f"paged cache unsupported for {cfg.family}")
     return PagedDecodeState(cache,
@@ -778,6 +784,10 @@ def make_paged_serve_step(cfg: ModelConfig, shape: ShapeConfig,
     (logits, PagedDecodeState).  Same sampling-visible math as the dense
     step — the attention core is bit-identical on CPU backends and a
     Pallas paged-attention kernel on TPU.
+
+    The layer scans carry the whole KV pool and scan over layer indices:
+    each layer writes its rows in place and the kernel reads them inside
+    the stacked pool, so no layer's pool is sliced, stacked or copied.
     """
 
     def serve_step(params, state: PagedDecodeState, batch):
@@ -791,10 +801,10 @@ def make_paged_serve_step(cfg: ModelConfig, shape: ShapeConfig,
 
         if cfg.family in ("dense", "moe"):
             def body(carry, xs):
-                x = carry
-                lp, pk, pv = xs
+                x, pk, pv = carry
+                lp, layer = xs
                 x, (pk, pv) = L.paged_decode_attention(
-                    lp["attn"], x, cfg, pool_k=pk, pool_v=pv,
+                    lp["attn"], x, cfg, pool_k=pk, pool_v=pv, layer=layer,
                     block_tables=bt, cache_len=clen, active=act, impl=impl)
                 if cfg.family == "moe":
                     from repro.models import moe as moe_lib
@@ -802,9 +812,10 @@ def make_paged_serve_step(cfg: ModelConfig, shape: ShapeConfig,
                 else:
                     with jax.named_scope("mlp"):
                         x = L.swiglu_block(lp["mlp"], x, cfg)
-                return x, (pk, pv)
-            h, (ks, vs) = _scan(
-                body, h, (params["layers"], cache["k"], cache["v"]),
+                return (x, pk, pv), ()
+            (h, ks, vs), _ = _scan(
+                body, (h, cache["k"], cache["v"]),
+                (params["layers"], jnp.arange(cfg.num_layers)),
                 unroll=unroll)
             new_cache = {"k": ks, "v": vs}
         elif cfg.family == "ssm":
@@ -821,8 +832,8 @@ def make_paged_serve_step(cfg: ModelConfig, shape: ShapeConfig,
         elif cfg.family == "hybrid":
             shared = params["shared"]
             def period_body(carry, xs):
-                x = carry
-                pp, st, cs, pk, pv = xs
+                x, pk, pv = carry
+                pp, st, cs, period = xs
                 def inner(c, ys):
                     lp, s1, c1 = ys
                     c, (s1, c1) = ssm_lib.mamba2_block(
@@ -831,14 +842,15 @@ def make_paged_serve_step(cfg: ModelConfig, shape: ShapeConfig,
                 x, (st, cs) = _scan(inner, x, (pp, st, cs), unroll=unroll)
                 x, (pk, pv) = L.paged_decode_attention(
                     shared["attn"], x, cfg, pool_k=pk, pool_v=pv,
-                    block_tables=bt, cache_len=clen, active=act, impl=impl)
+                    layer=period, block_tables=bt, cache_len=clen,
+                    active=act, impl=impl)
                 with jax.named_scope("mlp"):
                     x = L.swiglu_block(shared["mlp"], x, cfg)
-                return x, (st, cs, pk, pv)
-            h, (ssm, conv, ks, vs) = _scan(
-                period_body, h,
+                return (x, pk, pv), (st, cs)
+            (h, ks, vs), (ssm, conv) = _scan(
+                period_body, (h, cache["k"], cache["v"]),
                 (params["mamba"], cache["ssm"], cache["conv"],
-                 cache["k"], cache["v"]), unroll=unroll)
+                 jnp.arange(cache["k"].shape[0])), unroll=unroll)
             new_cache = {"ssm": ssm, "conv": conv, "k": ks, "v": vs}
         else:
             raise ValueError(cfg.family)
@@ -874,10 +886,12 @@ def make_paged_bulk_prefill(cfg: ModelConfig, shape: ShapeConfig,
     first token sits at absolute position ``off`` of slot ``slot``.
     Attention kv lands in the slot's blocks through its table (a
     block-table append); attention reads causally over history + chunk
-    (prefill-with-history).  Recurrent (ssm/conv) leaves continue from
-    the slot's carried state — zeros when ``off == 0`` — via the SSD
-    ``init_state`` threading, which is exactly equivalent to one long
-    prefill over the concatenated chunks.  Sets
+    (prefill-with-history).  The layer scan carries the whole pool and
+    each layer scatters its rows in place, as in the decode step.
+    Recurrent (ssm/conv) leaves continue from the slot's carried state —
+    zeros when ``off == 0`` — via the SSD ``init_state`` threading, which
+    is exactly equivalent to one long prefill over the concatenated
+    chunks.  Sets
     ``cache_len[slot] = off + n_real``.
 
     ``slot``/``off``/``n_real`` are traced: one compiled function per
@@ -904,10 +918,10 @@ def make_paged_bulk_prefill(cfg: ModelConfig, shape: ShapeConfig,
 
         if cfg.family in ("dense", "moe"):
             def body(carry, xs):
-                x = carry
-                lp, pk, pv = xs
+                x, pk, pv = carry
+                lp, layer = xs
                 x, pk, pv = L.paged_chunk_attention(
-                    lp["attn"], x, cfg, pool_k=pk, pool_v=pv,
+                    lp["attn"], x, cfg, pool_k=pk, pool_v=pv, layer=layer,
                     bt_row=bt_row, off=off, history=not first_chunk)
                 if cfg.family == "moe":
                     from repro.models import moe as moe_lib
@@ -915,9 +929,10 @@ def make_paged_bulk_prefill(cfg: ModelConfig, shape: ShapeConfig,
                 else:
                     with jax.named_scope("mlp"):
                         x = L.swiglu_block(lp["mlp"], x, cfg)
-                return x, (pk, pv)
-            _, (ks, vs) = jax.lax.scan(
-                body, h, (params["layers"], cache["k"], cache["v"]))
+                return (x, pk, pv), ()
+            (_, ks, vs), _ = jax.lax.scan(
+                body, (h, cache["k"], cache["v"]),
+                (params["layers"], jnp.arange(cfg.num_layers)))
             new_cache = {"k": ks, "v": vs}
         elif cfg.family == "ssm":
             if first_chunk:
@@ -950,8 +965,8 @@ def make_paged_bulk_prefill(cfg: ModelConfig, shape: ShapeConfig,
                 conv0 = jnp.where(first, 0, take_lane(cache["conv"], 2))
             shared = params["shared"]
             def period_body(carry, xs):
-                x = carry
-                pp, s0, c0, pk, pv = xs
+                x, pk, pv = carry
+                pp, s0, c0, period = xs
                 def inner(c, ys):
                     lp, s1, c1 = ys
                     c, (st, cv) = ssm_lib.mamba2_block(
@@ -960,13 +975,15 @@ def make_paged_bulk_prefill(cfg: ModelConfig, shape: ShapeConfig,
                 x, (sts, cvs) = jax.lax.scan(inner, x, (pp, s0, c0))
                 x, pk, pv = L.paged_chunk_attention(
                     shared["attn"], x, cfg, pool_k=pk, pool_v=pv,
-                    bt_row=bt_row, off=off, history=not first_chunk)
+                    layer=period, bt_row=bt_row, off=off,
+                    history=not first_chunk)
                 with jax.named_scope("mlp"):
                     x = L.swiglu_block(shared["mlp"], x, cfg)
-                return x, (sts, cvs, pk, pv)
-            _, (ssm, conv, ks, vs) = jax.lax.scan(
-                period_body, h,
-                (params["mamba"], ssm0, conv0, cache["k"], cache["v"]))
+                return (x, pk, pv), (sts, cvs)
+            (_, ks, vs), (ssm, conv) = jax.lax.scan(
+                period_body, (h, cache["k"], cache["v"]),
+                (params["mamba"], ssm0, conv0,
+                 jnp.arange(cache["k"].shape[0])))
             new_cache = {
                 "ssm": jax.lax.dynamic_update_slice(
                     cache["ssm"], ssm.astype(cache["ssm"].dtype),

@@ -740,13 +740,13 @@ class ServingEngine:
                 ax = self._cache_axes[k]
                 col = np.asarray(snap.cache[k])
                 if k in kv_keys:
-                    # contiguous column -> (max_blocks, block_size) at the
-                    # seq axis -> scatter the reserved prefix through the
-                    # fresh table (dense batch axis == paged block axis)
-                    sh = col.shape
+                    # contiguous column (..., S, KV, D) -> lane-dense
+                    # (..., max_blocks, block_size, KV*D) -> scatter the
+                    # reserved prefix through the fresh table (dense
+                    # batch axis == paged block axis)
                     blocked = col.reshape(
-                        sh[:ax] + (self.max_blocks, self.block_size)
-                        + sh[ax + 1:])
+                        col.shape[:ax] + (self.max_blocks, self.block_size,
+                                          -1))
                     sel = blocked[(slice(None),) * ax
                                   + (slice(0, len(blocks)),)]
                     idx = [slice(None)] * arr.ndim
@@ -948,13 +948,15 @@ class ServingEngine:
         for k, v in cache_host.items():
             ax = self._cache_axes[k]
             if k in kv_keys:
-                # gather the slot's blocks into the canonical
-                # contiguous column (block-size-agnostic snapshot)
+                # gather the slot's lane-dense blocks into the canonical
+                # contiguous (..., S, KV, D) column (block-size-agnostic
+                # snapshot)
                 blocks = list(self._alloc.owned(slot))
                 rows = v.take(blocks, axis=ax)
                 sh = rows.shape
                 merged = rows.reshape(
-                    sh[:ax] + (sh[ax] * sh[ax + 1],) + sh[ax + 2:])
+                    sh[:ax] + (sh[ax] * sh[ax + 1], self.cfg.num_kv_heads,
+                               self.cfg.head_dim))
                 pad = self.max_seq - merged.shape[ax]
                 if pad:
                     widths = [(0, 0)] * merged.ndim

@@ -29,6 +29,7 @@ import pytest
 
 from repro.cluster import ClusterMetrics
 from repro.configs import get_config
+from repro.configs.base import ShapeConfig
 from repro.kernels.paged_attention import (gather_pages, paged_attention,
                                            paged_attention_ref)
 from repro.kernels.paged_attention.kernel import paged_attention as \
@@ -83,8 +84,8 @@ def test_paged_kernel_matches_ref(heads, kv_heads, blocks_used):
     b, d, bs, nb, mb = 3, 16, 8, 12, 4
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(ks[0], (b, heads, d), jnp.float32)
-    k_pool = jax.random.normal(ks[1], (nb, bs, kv_heads, d), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (nb, bs, kv_heads, d), jnp.float32)
+    k_pool = jax.random.normal(ks[1], (nb, bs, kv_heads * d), jnp.float32)
+    v_pool = jax.random.normal(ks[2], (nb, bs, kv_heads * d), jnp.float32)
     rng = np.random.default_rng(3)
     bt = np.full((b, mb), nb, np.int32)
     kv_len = np.zeros(b, np.int32)
@@ -125,6 +126,8 @@ def test_paged_ref_bit_identical_to_dense_attention():
             pool_v = pool_v.at[blk].set(dense_v[i, j * bs:(j + 1) * bs])
     ref = full_attention(q, dense_k, dense_v, causal=False,
                          kv_len=kv_len)[:, 0]
+    # the pool's lane-dense rows: (nb, bs, KV*D)
+    pool_k, pool_v = (p.reshape(nb, bs, h * d) for p in (pool_k, pool_v))
     out = paged_attention_ref(q[:, 0], pool_k, pool_v, jnp.asarray(bt),
                               kv_len)
     assert bool(jnp.all(out == ref))
@@ -140,6 +143,126 @@ def test_gather_pages_clamps_sentinels():
     rows = gather_pages(pool, bt)
     assert rows.shape == (1, 6, 1, 2)
     assert bool(jnp.all(rows[0, :2] == pool[1]))  # real block intact
+
+
+# ------------------------------------------------- in-place pool writes
+def _stacked_pool(cfg, nb, bs, seed):
+    """A (L, nb, bs, KV*D) pool of distinct random rows, so a stray write
+    anywhere shows."""
+    shape = (cfg.num_layers, nb, bs, cfg.num_kv_heads * cfg.head_dim)
+    ks = jax.random.split(jax.random.PRNGKey(seed))
+    return {name: jax.random.normal(k, shape, jnp.bfloat16)
+            for name, k in zip(("k", "v"), ks)}
+
+
+def _assert_writes_only(before, after, allowed):
+    """Every (layer, block, offset) outside ``allowed`` comes out
+    bit-identical; every one inside it was written in every layer."""
+    for name in ("k", "v"):
+        old, new = np.asarray(before[name]), np.asarray(after[name])
+        changed = np.any(old != new, axis=-1)          # (L, nb, bs)
+        want = np.zeros_like(changed)
+        for blk, off in allowed:
+            want[:, blk, off] = True
+        np.testing.assert_array_equal(changed, want)
+
+
+@pytest.fixture(scope="module")
+def three_layer():
+    cfg = get_config("granite-8b").reduced().with_(num_layers=3)
+    return cfg, zoo.init_state(cfg, jax.random.PRNGKey(1)).params
+
+
+def test_paged_decode_writes_stay_in_their_layer(three_layer):
+    """One decode step: an active lane writes one row per layer, in
+    place; an inactive lane and an active lane whose block is a sentinel
+    write nothing, so the next layer's row 0 (where ``layer*nb + nb``
+    would land) is untouched."""
+    cfg, params = three_layer
+    nb, bs, lanes = 10, 4, 3
+    shape = ShapeConfig("serve", 32, lanes, "decode")
+    sentinel = nb
+    bt = np.full((lanes, 32 // bs), sentinel, np.int32)
+    bt[0, :2] = [2, 5]                  # active: position 5 -> block 5, off 1
+    bt[1, :1] = [0]                     # inactive lane
+    bt[2, :2] = [7, 8]                  # active, position 8 -> sentinel
+    clen = jnp.asarray([5, 3, 8], jnp.int32)
+    cache = _stacked_pool(cfg, nb, bs, seed=2)
+    state = zoo.PagedDecodeState(dict(cache), clen, jnp.asarray(bt))
+    step = jax.jit(zoo.make_paged_serve_step(cfg, shape, bs, nb))
+    _, out = step(params, state,
+                  {"tokens": jnp.asarray([[3], [4], [5]], jnp.int32),
+                   "active": jnp.asarray([1, 0, 1], jnp.int32)})
+    _assert_writes_only(cache, out.cache, [(5, 1)])
+
+
+@pytest.mark.parametrize("first", [True, False])
+def test_paged_chunk_prefill_writes_stay_in_their_layer(three_layer, first):
+    """A chunk prefill writes the slot's own rows in every layer, in
+    place: the chunk's padded tail runs into a sentinel table entry and
+    is dropped, never written into the next layer's row 0.  The history
+    chunk writes back the slot's other rows unchanged."""
+    cfg, params = three_layer
+    nb, bs, lanes, chunk = 10, 4, 3, 8
+    shape = ShapeConfig("serve", 32, lanes, "decode")
+    off = 0 if first else 4
+    blocks = [3] if first else [3, 7]    # slot 1's blocks, then sentinels
+    blk = blocks[-1]                     # positions off..off+3 land here
+    bt = np.full((lanes, 32 // bs), nb, np.int32)
+    bt[1, :len(blocks)] = blocks
+    cache = _stacked_pool(cfg, nb, bs, seed=3)
+    state = zoo.PagedDecodeState(dict(cache), jnp.zeros((lanes,), jnp.int32),
+                                 jnp.asarray(bt))
+    fn = jax.jit(zoo.make_paged_bulk_prefill(cfg, shape, chunk, bs, nb,
+                                             first_chunk=first))
+    tokens = jnp.asarray([[9, 8, 7, 6, 0, 0, 0, 0]], jnp.int32)
+    out = fn(params, state, tokens, np.int32(1), np.int32(off), np.int32(4))
+    # first: 4 real + 4 pad positions, block 3 then a sentinel;
+    # history: positions 4..11, block 7 then a sentinel
+    _assert_writes_only(cache, out.cache, [(blk, o) for o in range(bs)])
+    assert int(out.cache_len[1]) == off + 4
+
+
+def test_lane_dense_pack_unpack_round_trip(models):
+    """Packing a lane-dense pool gives the canonical ``(L, S, KV, D)``
+    snapshot columns: head h of position p is lanes [h*D, (h+1)*D) of
+    its block row, equal to what the dense engine packs for the same
+    request, and an unpack -> pack round trip returns them unchanged."""
+    cfg, params = models["granite-8b"]
+    reqs = _requests(2, seed=11, plen=(10, 20), max_new=(8, 10))
+    copy = [Request(r.rid, r.prompt.copy(), r.max_new_tokens) for r in reqs]
+    dense = _engine(cfg, params)
+    paged = _engine(cfg, params, cache_mode="paged", block_size=8)
+    for eng, rs in ((dense, copy), (paged, reqs)):
+        for r in rs:
+            eng.submit(r)
+        eng.step_many(3)
+    pool = {k: np.asarray(v) for k, v in paged.state.cache.items()}
+    owned = {paged._slots[s].rid: list(paged._alloc.owned(s))
+             for s in range(paged.batch) if paged._slots[s] is not None}
+    units = paged.pack()
+    want = {u.snapshot.request.rid: u.snapshot for u in dense.pack()}
+    L, S = cfg.num_layers, paged.max_seq
+    kv, d = cfg.num_kv_heads, cfg.head_dim
+    for u in units:
+        snap = u.snapshot
+        n = snap.cache_len
+        for k in ("k", "v"):
+            col = snap.cache[k]
+            assert col.shape == (L, S, kv, d)
+            blocks = owned[snap.request.rid]
+            rows = pool[k][:, blocks].reshape(L, -1, kv, d)
+            np.testing.assert_array_equal(col[:, :rows.shape[1]], rows)
+            np.testing.assert_array_equal(
+                col[:, :n], want[snap.request.rid].cache[k][:, :n])
+    again = _engine(cfg, params, cache_mode="paged", block_size=8)
+    again.unpack(units)
+    again._admit()
+    repacked = {u.snapshot.request.rid: u.snapshot for u in again.pack()}
+    for u in units:
+        for k in ("k", "v"):
+            np.testing.assert_array_equal(
+                repacked[u.snapshot.request.rid].cache[k], u.snapshot.cache[k])
 
 
 # ----------------------------------------------------- engine equivalence

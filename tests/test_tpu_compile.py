@@ -15,6 +15,7 @@ compiles in this one file for the same reason.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.jacobi.kernel import jacobi_step
+from repro.kernels.paged_attention import ops as paged_ops
 from repro.kernels.paged_attention.kernel import paged_attention
 from repro.kernels.ssd.kernel import ssd_intra_chunk
 from repro.models import model_zoo as zoo
@@ -70,10 +72,11 @@ def _has_kernel(compiled) -> bool:
 @pytest.mark.parametrize("block_size", [8, 16, 128])
 def test_paged_kernel_compiles_at_granite_decode_width(one_chip, block_size):
     """granite-8b decode: 8 lanes, 32 q / 8 kv heads of 128, max_seq 1024,
-    the pool holding every lane's whole budget (the engine default)."""
+    the pool holding every lane's whole budget (the engine default), its
+    rows lane-dense ``(block_size, KV*D)``."""
     lanes, heads, kv, d, max_seq = 8, 32, 8, 128, 1024
     max_blocks = max_seq // block_size
-    pool = (lanes * max_blocks, block_size, kv, d)
+    pool = (lanes * max_blocks, block_size, kv * d)
     fn = jax.jit(functools.partial(paged_attention, interpret=False))
     compiled = fn.lower(
         _sds(one_chip, (lanes, heads, d), jnp.bfloat16),
@@ -140,3 +143,82 @@ def test_dense_decode_window_compiles_at_granite_width(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < HBM_BYTES, used
+
+
+# ------------------------------------------------ paged serving programs
+# granite3b.batch's deployment: granite-3-2b whole, 16 lanes, max_seq
+# 3072, a pool of 1536 blocks of 16
+PAGED_LANES, PAGED_MAX_SEQ, PAGED_BS, PAGED_NB = 16, 3072, 16, 1536
+_INSTR = re.compile(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                    r"(copy|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _pool_moves(hlo: str, bs: int, kvd: int, layer_rows: int):
+    """Copies and (update-)slices of pool rows covering a layer or more:
+    what a layer scan does to a pool it does not carry in place."""
+    moves = []
+    for m in _INSTR.finditer(hlo):
+        dims = [int(x) for x in m.group(1).split(",") if x]
+        if dims[-2:] == [bs, kvd]:
+            rows = 1
+            for x in dims[:-2]:
+                rows *= x
+            if rows >= layer_rows:
+                moves.append(m.group(0).strip())
+    return moves
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Trace the paged kernel as on a TPU: the Mosaic kernel, not the
+    CPU reference or interpret mode."""
+    monkeypatch.setattr(paged_ops, "_on_tpu", lambda: True)
+    paged_ops.paged_attention.clear_cache()
+    yield
+    paged_ops.paged_attention.clear_cache()
+
+
+@pytest.mark.parametrize("program", ["decode_loop", "prefill_first",
+                                     "prefill_history"])
+def test_paged_programs_update_the_pool_in_place(one_chip, mosaic, program):
+    """The paged decode window and both chunk-prefill programs at
+    granite3b.batch's shapes: the layer scans carry the lane-dense pool
+    and write it in place, so no op copies, slices or stacks a layer's
+    pool or more, and the temporaries stay below one layer's pool."""
+    cfg = get_config("granite-3-2b").with_(param_dtype="bfloat16")
+    shape = ShapeConfig("serve", PAGED_MAX_SEQ, PAGED_LANES, "decode")
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+
+    params = placed(zoo.abstract_state(cfg).params)
+    state = placed(zoo.abstract_paged_decode_state(cfg, shape, PAGED_BS,
+                                                   PAGED_NB))
+    if program == "decode_loop":
+        fn = zoo.make_paged_decode_loop(cfg, shape, 8, PAGED_BS, PAGED_NB)
+        args = (params, state,
+                placed(jax.eval_shape(
+                    lambda: zoo.init_sample_state(cfg, shape, seed=0))),
+                _sds(one_chip, (PAGED_LANES, PAGED_MAX_SEQ), jnp.int32))
+        donate = (1, 2)
+    else:
+        fn = zoo.make_paged_bulk_prefill(
+            cfg, shape, 256, PAGED_BS, PAGED_NB,
+            first_chunk=program == "prefill_first")
+        scalar = _sds(one_chip, (), jnp.int32)
+        args = (params, state, _sds(one_chip, (1, 256), jnp.int32),
+                scalar, scalar, scalar)
+        donate = (1,)
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    hlo = compiled.as_text()
+    pool = state.cache["k"]
+    assert pool.shape == (cfg.num_layers, PAGED_NB, PAGED_BS,
+                          cfg.num_kv_heads * cfg.head_dim)
+    if program == "decode_loop":
+        assert _has_kernel(compiled)
+    assert re.search(r"= bf16\[%d,%d,%d\]\S* scatter\(" % (
+        cfg.num_layers * PAGED_NB, PAGED_BS, pool.shape[-1]), hlo)
+    assert _pool_moves(hlo, PAGED_BS, pool.shape[-1], PAGED_NB) == []
+    layer_bytes = pool.size // cfg.num_layers * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
