@@ -4,11 +4,12 @@
 
 For each seed, in one process, the cell runs as the benchmark runs it (a
 shorter window) and the sampled requests are judged twice: the program's
-served tokens, and the tokens that the control, the float32 reference
-with every linear layer's operands rounded to float8, puts first at the
-same positions.  The program's widest gaps are the lower reading of the
-limit in ``bench/limits/<cell>.json``; the control's smallest is its
-upper reading.  The benchmark's own runs never run the control.
+served tokens, and the tokens that the control of the configuration's
+model module puts first at the same positions (the dense model's: the
+float32 reference with every linear layer's operands rounded to
+float8).  The program's widest gaps are the lower reading of the limit
+in ``bench/limits/<cell>.json``; the control's smallest is its upper
+reading.  The benchmark's own runs never run the control.
 """
 
 from __future__ import annotations
@@ -35,7 +36,7 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args()
     spec = core.load_spec()
-    cell, _, cfg_file = core.find_cell(spec, args.workload)
+    cell, cfg_file, model = core.find_cell(spec, args.workload)
     try:
         core.require_chips(int(cell["chips"]))
     except core.NoChip as e:
@@ -47,7 +48,7 @@ def main() -> int:
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         result, compared, _ = serve_cell.run(
-            cell, cfg_file, seed, args.seconds, t0, counter,
+            cell, cfg_file, model, seed, args.seconds, t0, counter,
             control=True)
         core.log("control_seed", seed=seed, correct=result["correct"],
                  program_gap=compared["max_logit_gap"]["value"],

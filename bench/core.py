@@ -1,5 +1,6 @@
-"""Plumbing shared by every cell: the cell table, the chip check, the
-compile cache, compile counting, the table of peaks and the result line.
+"""Plumbing shared by every cell: the cell table, the model module a
+configuration names, the chip check, the compile cache, compile
+counting, the table of peaks, the metric readers and the result line.
 """
 
 from __future__ import annotations
@@ -7,11 +8,15 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+import re
 import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = Path(__file__).resolve().parent
+# bench/models/<model>.py, one per model family a configuration can name
+MODELS = BENCH / "models"
+_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 
 # tracing, lowering and compiling (or loading a compiled program from the
 # persistent cache) each report one of these events; a run counts them
@@ -35,13 +40,31 @@ def load_spec() -> dict:
 
 
 def find_cell(spec: dict, name: str):
+    """The cell's entry, its configuration file and the model module
+    that file names (the run stops here if it names none)."""
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r}; known: "
                          f"{sorted(cells)}")
     cell = cells[name]
     cfg = {c["name"]: c for c in spec["configs"]}[cell["config"]]
-    return cell, cfg, json.loads((ROOT / cfg["file"]).read_text())
+    cfg_file = json.loads((ROOT / cfg["file"]).read_text())
+    return cell, cfg_file, load_model(cfg_file, cfg["file"])
+
+
+def load_model(cfg_file: dict, where: str):
+    """The module ``bench/models/<model>.py`` that the configuration's
+    ``"model"`` key names: its sizes, seeded weights, float32 reference
+    and counts of operations and bytes.  ``where`` names the file in
+    the message that stops a run whose configuration names none."""
+    name = cfg_file.get("model")
+    if not isinstance(name, str) or not _NAME.fullmatch(name):
+        raise SystemExit(f"{where}: needs a \"model\" key naming a module "
+                         f"of {MODELS}, found {name!r}")
+    path = MODELS / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"{where}: model {name!r} has no module {path}")
+    return _load(path, "bench_model_" + name)
 
 
 def cell_metrics(spec: dict, cell: str, trace: bool):
@@ -110,14 +133,20 @@ def device_info(n_chips: int) -> dict:
 
 def load_reader(name: str):
     """The per-layer metric reader ``bench/metrics/<name>.py``."""
-    path = BENCH / "metrics" / f"{name}.py"
+    return _load(BENCH / "metrics" / f"{name}.py",
+                 "bench_metric_" + name).read
+
+
+def _load(path: Path, module: str):
+    """Execute one file of the benchmark as a module of its own; its
+    directory is put on the path for the helpers it imports."""
     if str(path.parent) not in sys.path:
         sys.path.insert(0, str(path.parent))
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
+        module.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
 def emit(result: dict, compared: dict):

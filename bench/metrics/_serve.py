@@ -1,6 +1,8 @@
 """What the serving readers share: device times from the trace, and
-counts of the work in the measured window from the host's records."""
-import flops
+counts of the work in the measured window from the host's records.
+Operations and bytes come from the run's model module (``run.model``,
+``bench/models/<model>.py``), never from a model's code directly."""
+import trace_reduce
 
 DECODE_PROGRAM = "decode_loop"
 ATTN_KERNEL = "paged_attention"
@@ -10,8 +12,20 @@ def kernel_device(run):
     return run.trace.ops_matching(ATTN_KERNEL) if run.trace else (0.0, 0)
 
 
+def scope_seconds(run, program):
+    """{model scope: device op seconds} in the traced window, over the
+    programs whose module name holds ``program``: the scopes are those
+    the run's model module declares (``SCOPES``), ``none`` ops traced
+    outside every one, ``unmapped`` instructions the scope map lacks;
+    empty where the run has no trace or scopes."""
+    if not (run.trace and run.scopes):
+        return {}
+    return trace_reduce.scope_seconds(run.trace.instr_s, run.scopes, program)
+
+
 def prefill_flops(run):
-    return sum(flops.prefill_flops(run.dims, run.recs[rid].prompt_len - 1)
+    return sum(run.model.prefill_flops(run.dims,
+                                       run.recs[rid].prompt_len - 1)
                for w in run.windows for rid in w.admitted)
 
 
@@ -37,6 +51,6 @@ def decode_step_ms(run):
 
 
 def window_model_flops(run):
-    dec = sum(flops.decode_flops(run.dims, ctx)
+    dec = sum(run.model.decode_flops(run.dims, ctx)
               for ctx in decode_ctx_by_step(run))
     return prefill_flops(run) + dec

@@ -4,9 +4,12 @@
 
 The cell, its configuration and its traffic mix are found by name from
 ``BENCHMARK.json``: ``bench/configs/<config>.json`` holds the sizes as
-run, ``bench/traffic/<mix>.json`` the parameters of the one generator,
-``bench/limits/<cell>.json`` the limits of the correctness comparison,
-and ``bench/metrics/<metric>.py`` one reader per per-layer metric.
+run and names its model in a ``"model"`` key, ``bench/models/<model>.py``
+that model's sizes, seeded weights, float32 reference and counts of
+operations and bytes, ``bench/traffic/<mix>.json`` the parameters of the
+one generator, ``bench/limits/<cell>.json`` the limits of the
+correctness comparison, and ``bench/metrics/<metric>.py`` one reader
+per per-layer metric.
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the profiler and the line carries
@@ -78,30 +81,33 @@ def per_layer(spec, cell, data) -> dict:
     return out
 
 
-def main() -> int:
+def measure(argv=None, tracer_type=Tracer):
+    """One run of the cell that the arguments name: (result line,
+    compared numbers, RunData), or None where it cannot be measured
+    here.  ``tracer_type`` makes the profiler of a traced run."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     spec = core.load_spec()
-    cell, _, cfg_file = core.find_cell(spec, args.workload)
+    cell, cfg_file, model = core.find_cell(spec, args.workload)
     try:
         core.require_chips(int(cell["chips"]))
     except core.NoChip as e:
         print(f"bench: {e}", file=sys.stderr)
-        return 1
+        return None
     cache = core.enable_compile_cache()
     counter = core.CompileCounter()
     core.log("start", workload=cell["name"], seed=args.seed,
              seconds=args.seconds, trace=args.trace, compile_cache=cache)
 
     import serve_cell
-    tracer = Tracer() if args.trace else None
+    tracer = tracer_type() if args.trace else None
     result, compared, data = serve_cell.run(
-        cell, cfg_file, args.seed, args.seconds, T_START, counter,
+        cell, cfg_file, model, args.seed, args.seconds, T_START, counter,
         tracer=tracer)
     setup_s = result.pop("setup_s")
     if tracer is not None:
@@ -124,7 +130,14 @@ def main() -> int:
                                    "metrics", "device")}
     if "breakdown" in result:
         out["breakdown"] = result["breakdown"]
-    core.emit(out, compared)
+    return out, compared, data
+
+
+def main() -> int:
+    got = measure()
+    if got is None:
+        return 1
+    core.emit(*got[:2])
     return 0
 
 

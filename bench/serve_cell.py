@@ -15,10 +15,17 @@ prefill chunk programs that the mix's prompt lengths need (by the
 engine's own chunk choice) and the one decode window.  Compile events
 inside the window are counted and printed.
 
-After the window: the peak device memory is read, a seeded sample of
-finished requests (the longest answer among them, and others from as
+After the window: the peak device memory is read, in traced runs the
+(module, instruction) -> model scope map of the engine's programs is
+taken (over the scopes that the model module declares), a seeded sample
+of finished requests (the longest answer among them, and others from as
 many different lanes) is kept on the host, the engine is freed, and the
 float32 reference judges every served token.
+
+Everything about the model comes from the module ``bench/models/<model>.py``
+that the configuration file's ``"model"`` key names (``core.load_model``):
+its sizes, its seeded weights, its reference, its counts and the names
+of its scopes.
 """
 
 from __future__ import annotations
@@ -36,9 +43,8 @@ import jax
 import numpy as np
 
 import core
-import reference
+import trace_reduce
 import traffic
-from weights import Dims, program_params
 
 LIMITS_DIR = Path(__file__).resolve().parent / "limits"
 span = jax.profiler.TraceAnnotation
@@ -109,28 +115,6 @@ class Stream:
 _PROBE = jax.jit(lambda fed: fed + 0)
 
 
-def program_config(cfg_file: dict):
-    """The program's ModelConfig for a configuration file, checked
-    against the file's sizes so that the file is what runs."""
-    from repro.configs import get_config
-    prog = cfg_file["program"]
-    cfg = get_config(prog["arch"]).with_(**prog["overrides"])
-    want = {"num_layers": cfg_file["num_hidden_layers"],
-            "d_model": cfg_file["hidden_size"],
-            "num_heads": cfg_file["num_attention_heads"],
-            "num_kv_heads": cfg_file["num_key_value_heads"],
-            "d_ff": cfg_file["intermediate_size"],
-            "vocab_size": cfg_file["vocab_size"],
-            "tie_embeddings": cfg_file["tie_word_embeddings"],
-            "rope_theta": cfg_file["rope_theta"],
-            "norm_eps": cfg_file["rms_norm_eps"],
-            "param_dtype": cfg_file["deployment"]["dtype"]}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        raise SystemExit(f"program config {got} differs from file {want}")
-    return cfg
-
-
 def _chunk_plan(eng, prompt_len: int):
     """(bucket, first) of each prefill chunk the engine issues for a
     prompt of this length, by the engine's own chunk choice."""
@@ -144,11 +128,17 @@ def _chunk_plan(eng, prompt_len: int):
     return tuple(plan)
 
 
+# the plan of a prompt that gets no chunk at all (a recurrent model's
+# prompt shorter than its smallest bucket): the engine then writes the
+# lane's cache length eagerly, which compiles programs of its own
+NO_CHUNK = (0, True)
+
+
 def _warm_prompts(eng, mix: dict) -> List[int]:
     """One prompt length for each prefill program the mix can reach."""
     need, reps = set(), []
     for p in traffic.warm_lengths(mix):
-        sig = set(_chunk_plan(eng, p)) - need
+        sig = set(_chunk_plan(eng, p) or (NO_CHUNK,)) - need
         if sig:
             need |= sig
             reps.append(p)
@@ -194,7 +184,7 @@ def _record(eng, k: int, stats, prev_fed, recs):
 @dataclasses.dataclass
 class RunData:
     """Everything a metric reader may read."""
-    dims: Dims
+    dims: object                  # the sizes, as ``model.dims`` gives them
     peak: dict
     lanes: int
     seconds: float
@@ -202,6 +192,9 @@ class RunData:
     windows: List[Window]
     recs: Dict[int, Rec]
     trace: object = None          # trace_reduce.TraceSummary
+    model: object = None          # the module of bench/models/ dims are of
+    # (module, instruction) -> model scope of the engine's programs
+    scopes: Optional[Dict[tuple, str]] = None
 
 
 def _limits(cell_name: str) -> dict:
@@ -245,18 +238,20 @@ def _sample(done: List, recs: Dict[int, Rec], seed: int, check: dict):
             for r in out]
 
 
-def run(cell: dict, cfg_file: dict, seed: int, seconds: float,
+def run(cell: dict, cfg_file: dict, model, seed: int, seconds: float,
         t_start: float, counter, *, tracer=None, mix=None, limits=None,
         peak=None, control=False):
-    """One run of a serving cell; returns (result, compared, RunData)."""
+    """One run of a serving cell of the configuration ``cfg_file``, whose
+    model module (``core.load_model``) is ``model``; returns (result,
+    compared, RunData)."""
     from repro.serving.engine import Request, ServingEngine
     dep = cfg_file["deployment"]
     mix = mix or traffic.load_mix(cell["traffic"])
-    cfg = program_config(cfg_file)
-    dims = Dims.from_config(cfg_file)
+    cfg = model.program_config(cfg_file)
+    dims = model.dims(cfg_file)
     peak = peak or core.peaks(jax.devices()[0].device_kind)
 
-    params = jax.block_until_ready(program_params(dims, seed))
+    params = jax.block_until_ready(model.program_params(dims, seed))
     eng = ServingEngine(cfg, params, batch_size=dep["lanes"],
                         max_seq=dep["max_seq"], cache_mode="paged",
                         block_size=dep["block_size"],
@@ -335,16 +330,23 @@ def run(cell: dict, cfg_file: dict, seed: int, seconds: float,
              completed=len(done),
              peak_bytes_in_use=device["memory_peak_bytes"])
 
+    scopes = None
+    if tracer is not None:
+        # lowered through jit's cache: the programs the window ran
+        t = time.perf_counter()
+        scopes = trace_reduce.scope_map(eng.program_hlo(), model.SCOPES)
+        core.log("scopes", seconds=time.perf_counter() - t,
+                 instructions=len(scopes))
     sample = _sample(done, recs, seed, mix["check"])
     del eng, params, reqs, done
     gc.collect()
 
     t_ref = time.perf_counter()
-    gaps = [reference.served_gap(dims, seed, p, out) for _, p, out in sample]
+    gaps = [model.served_gap(dims, seed, p, out) for _, p, out in sample]
     gap = max(gaps, default=float("inf"))
     ctl = None
     if control:
-        ctl = max(reference.served_gap(dims, seed, p, out, control=True)
+        ctl = max(model.served_gap(dims, seed, p, out, control=True)
                   for _, p, out in sample)
         core.log("control", program_gaps=gaps, control_max=ctl)
     limits = limits or _limits(cell["name"])
@@ -363,7 +365,8 @@ def run(cell: dict, cfg_file: dict, seed: int, seconds: float,
     core.log("reference", seconds=time.perf_counter() - t_ref,
              sampled=[rid for rid, _, _ in sample], lanes=lanes_sampled)
 
-    data = RunData(dims, peak, dep["lanes"], seconds, wall_s, windows, recs)
+    data = RunData(dims, peak, dep["lanes"], seconds, wall_s, windows, recs,
+                   model=model, scopes=scopes)
     result = {"correct": bool(correct),
               "attempted": sum(r.admit_window is not None
                                for r in recs.values()),

@@ -13,6 +13,10 @@ import core
 import engine_trace as et
 import serve_cell
 import trace_reduce
+from metrics import _serve
+
+
+DENSE = core.load_model({"model": "dense"}, "the dense model")
 
 
 def ev(name, start, dur):
@@ -42,13 +46,21 @@ PREFILL_B = PREFILL_A.replace("kv_write/scatter", "mlp/dot")
 
 
 def test_an_instruction_takes_its_innermost_model_scope():
-    assert et.scope_of("jit(f)/while/body/closed_call/kv_write/scatter") \
+    def scope_of(op_name):
+        return trace_reduce.scope_of(op_name, DENSE.SCOPES)
+    assert scope_of("jit(f)/while/body/closed_call/kv_write/scatter") \
         == "kv_write"
-    assert et.scope_of("jit(f)/while/body/qkv/attention/pallas_call") \
+    assert scope_of("jit(f)/while/body/qkv/attention/pallas_call") \
         == "attention"
-    assert et.scope_of("jit(f)/while/body/dynamic_slice") == "none"
-    assert et.instruction("%fusion.12 = bf16[4]{0} fusion(%p)") == "fusion.12"
-    smap = et.scope_map({"decode_loop.k4": HLO})
+    assert scope_of("jit(f)/while/body/dynamic_slice") == "none"
+    # a scope is one the model declares, wherever it sits in the path
+    assert trace_reduce.scope_of("jit(f)/ssm/while/body/dot",
+                                 DENSE.SCOPES) == "none"
+    assert trace_reduce.scope_of("jit(f)/ssm/while/body/dot",
+                                 DENSE.SCOPES + ("ssm",)) == "ssm"
+    assert trace_reduce.instruction("%fusion.12 = bf16[4]{0} fusion(%p)") \
+        == "fusion.12"
+    smap = trace_reduce.scope_map({"decode_loop.k4": HLO}, DENSE.SCOPES)
     assert smap[("jit_decode_loop", "fusion.3")] == "mlp"
     assert smap[("jit_decode_loop", "custom-call.7")] == "attention"
     assert smap[("jit_decode_loop", "copy.2")] == "none"
@@ -58,11 +70,13 @@ def test_an_instruction_takes_its_innermost_model_scope():
 
 
 def test_programs_that_share_a_module_name_and_disagree_are_mixed():
-    smap = et.scope_map({"paged_prefill.c16": PREFILL_A,
-                         "paged_prefill.c64": PREFILL_A})
+    smap = trace_reduce.scope_map({"paged_prefill.c16": PREFILL_A,
+                                   "paged_prefill.c64": PREFILL_A},
+                                  DENSE.SCOPES)
     assert smap[("jit_paged_prefill", "fusion.1")] == "kv_write"
-    smap = et.scope_map({"paged_prefill.c16": PREFILL_A,
-                         "paged_prefill.c64": PREFILL_B})
+    smap = trace_reduce.scope_map({"paged_prefill.c16": PREFILL_A,
+                                   "paged_prefill.c64": PREFILL_B},
+                                  DENSE.SCOPES)
     assert smap[("jit_paged_prefill", "fusion.1")] == "mixed"
 
 
@@ -113,9 +127,17 @@ def planes():
     return [host, dev]
 
 
+def _scoped(ps):
+    """What a traced run keeps of ``ps``: the reduction and the scope
+    map of the programs ``HLO`` and ``PREFILL_A``."""
+    return NS(trace=trace_reduce.reduce_planes(ps),
+              scopes=trace_reduce.scope_map(
+                  {"decode_loop.k4": HLO, "paged_prefill.c16": PREFILL_A},
+                  DENSE.SCOPES))
+
+
 def test_analyze_names_gaps_checks_dispatch_and_splits_by_scope():
-    res = et.analyze(planes(), {"decode_loop.k4": HLO,
-                                "paged_prefill.c16": PREFILL_A})
+    res = et.analyze(planes())
     assert res["window_s"] == pytest.approx(900e-9)
     # idle [100, 120), [200, 210), [400, 610) and [800, 1000)
     assert res["gaps_by_span"] == {
@@ -133,10 +155,13 @@ def test_analyze_names_gaps_checks_dispatch_and_splits_by_scope():
                                "negative": 0}
     # decode ops: mlp 100 + 190, attention 50, unscoped copy 40; the
     # while loops enclose their bodies and are left out
+    run = _scoped(planes())
+    res = et.by_scope(run)
     assert res["decode_by_scope"] == {"mlp": pytest.approx(290e-9),
                                       "attention": pytest.approx(50e-9),
                                       "none": pytest.approx(40e-9)}
-    assert res["decode_unscoped_share"] == pytest.approx(100 * 40 / 380)
+    assert core.load_reader("decode_unscoped_share.batch")(run) == \
+        pytest.approx(100 * 40 / 380)
     assert res["decode_top_unscoped"][0][0] == "copy.2"
     assert res["prefill_by_scope"] == {"kv_write": pytest.approx(80e-9)}
 
@@ -147,7 +172,8 @@ def test_analyze_without_hlo_or_engine_spans():
                              if not e.name.startswith("serve.")]
     res = et.analyze(ps)
     assert res["engine_idle_ms"] is None
-    assert "decode_by_scope" not in res
+    assert et.by_scope(NS(trace=trace_reduce.reduce_planes(ps),
+                          scopes=None)) == {}
     assert set(res["gaps_by_span"]) <= {"bench.step_many", "none"}
 
 
@@ -163,9 +189,9 @@ def test_the_tool_reads_a_tiny_engines_programs():
     eng.submit(Request(0, np.arange(1, 71, dtype=np.int32), 3))
     while eng.n_active or eng.n_queued:
         eng.step_many(4)
-    smap = et.scope_map(eng.program_hlo())
+    smap = trace_reduce.scope_map(eng.program_hlo(), DENSE.SCOPES)
     decode = {s for (m, _), s in smap.items() if m == "jit_decode_loop"}
-    assert decode == set(et.SCOPES) | {"none"}
+    assert decode == set(DENSE.SCOPES) | {"none"}
     assert {m for m, _ in smap} == {"jit_decode_loop", "jit_paged_prefill"}
 
 
@@ -178,12 +204,12 @@ def _run(trace, admitted, prompt_lens):
                               trace=trace)
 
 
-def _summary(prefill_s):
+def _summary(prefill_s, instr_s=None):
     return trace_reduce.TraceSummary(
         window_s=1.0, busy_s=0.9, chips=1, op_s={}, op_count={},
         module_s={"jit_paged_prefill": prefill_s, "jit_decode_loop": 0.5},
         module_count={"jit_paged_prefill": 3, "jit_decode_loop": 2},
-        gaps=[])
+        gaps=[], instr_s=instr_s or {})
 
 
 def test_prefill_reader_on_a_hand_built_run():
@@ -196,3 +222,34 @@ def test_prefill_reader_on_a_hand_built_run():
     assert read(_run(None, [[1]], {1: 10})) is None
     assert read(_run(_summary(0.0), [[1]], {1: 10})) is None
     assert read(_run(_summary(0.4), [[]], {1: 10})) is None
+
+
+def test_decode_unscoped_share_on_a_hand_built_run():
+    """Decode op time by scope through the programs' scope map: mlp
+    0.3 s, attention 0.5 s, the unscoped copy 0.1 s and an instruction
+    the map lacks 0.05 s, counted as unscoped; the prefill program's
+    time is not decode's."""
+    read = core.load_reader("decode_unscoped_share.batch")
+    smap = trace_reduce.scope_map({"decode_loop.k4": HLO,
+                                   "paged_prefill.c16": PREFILL_A},
+                                  DENSE.SCOPES)
+    instr = {("jit_decode_loop", "fusion.3"): 0.3,
+             ("jit_decode_loop", "custom-call.7"): 0.5,
+             ("jit_decode_loop", "copy.2"): 0.1,
+             ("jit_decode_loop", "fusion.99"): 0.05,
+             ("jit_paged_prefill", "fusion.1"): 0.2}
+    run = _run(_summary(0.2, instr), [[1]], {1: 10})
+    run.scopes = smap
+    assert _serve.scope_seconds(run, "decode_loop") == {
+        "mlp": 0.3, "attention": 0.5, "none": 0.1, "unmapped": 0.05}
+    assert _serve.scope_seconds(run, "paged_prefill") == {"kv_write": 0.2}
+    assert read(run) == pytest.approx(100 * (0.1 + 0.05) / 0.95)
+    # a map that matches none of the trace's instructions reads 100%
+    run.scopes = {("jit_decode_loop", "other.1"): "mlp"}
+    assert read(run) == pytest.approx(100.0)
+    run.scopes = None
+    assert read(run) is None
+    assert read(_run(None, [[1]], {1: 10})) is None
+    run.scopes = smap
+    run.trace = _summary(0.2, {("jit_paged_prefill", "fusion.1"): 0.2})
+    assert read(run) is None

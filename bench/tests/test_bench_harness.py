@@ -78,6 +78,8 @@ def _broken(fault: str, vocab: int, switch: Fault):
             # every stored token moved by one where the loop writes it
             return state, sample._replace(
                 out_buf=(sample.out_buf + 1) % vocab)
+        # a traced run reads the scopes of the program the loop wraps
+        step.lower = loop.lower
         return step
     return patched
 

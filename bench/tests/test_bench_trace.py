@@ -7,6 +7,7 @@ from types import SimpleNamespace as NS
 import pytest
 
 import core
+import engine_trace
 import trace_reduce
 
 RECORDED = Path(__file__).resolve().parent / "data" / "probe.xplane.pb"
@@ -87,3 +88,36 @@ def test_recorded_chip_trace():
     assert s.ops_matching("paged_attention")[1] == 3
     assert s.modules_matching("jit__lambda")[1] == 6
     assert s.gaps[0][0] == "bench.window_wait"
+
+
+def test_op_time_by_program_and_instruction():
+    """Each op counts under the program run that encloses its start; the
+    while loop that encloses its body is left out, as in ``op_s``."""
+    s = trace_reduce.reduce_planes(planes())
+    assert s.instr_s == {
+        ("jit_decode_loop", "fusion.1"): pytest.approx(100e-9),
+        ("jit_decode_loop", "paged_attention"): pytest.approx(150e-9),
+        ("jit_paged_prefill", "fusion.2"): pytest.approx(180e-9)}
+    assert sum(s.instr_s.values()) == pytest.approx(sum(s.op_s.values()))
+
+
+def test_recorded_trace_op_time_agrees_with_one_walk_of_its_device():
+    """On the recorded chip trace, the seconds per (program, instruction)
+    that the reduction keeps equal one ``op_time`` walk over the first
+    device plane's events, shifted onto the host clock, with the window
+    taken from ``engine_trace``'s host spans."""
+    from jax.profiler import ProfileData
+    s = trace_reduce.reduce_file(str(RECORDED))
+    ps = list(ProfileData.from_file(str(RECORDED)).planes)
+    marks = [(a, b) for n, a, b in engine_trace.host_spans(ps)
+             if n == trace_reduce.WINDOW_SPAN]
+    lo, hi = min(a for a, _ in marks), max(b for _, b in marks)
+    dev = next(p for p in ps if p.name.startswith(trace_reduce.DEVICE_PREFIX))
+    shift = trace_reduce._shift(dev, trace_reduce._launches(ps, lo, hi))
+    mods, ops = trace_reduce.device_events(dev, shift, lo, hi)
+    want = trace_reduce.op_time(mods, ops, lo, hi)
+    assert want and s.instr_s == pytest.approx(want)
+    kernel = {k: v for k, v in s.instr_s.items() if "paged_attention" in k[1]}
+    assert {m for m, _ in kernel} == {"jit__lambda"}
+    assert sum(kernel.values()) == pytest.approx(
+        s.ops_matching("paged_attention")[0])

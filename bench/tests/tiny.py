@@ -8,6 +8,7 @@ import serve_cell
 
 def cfg_file(tied: bool) -> dict:
     return {
+        "model": "dense",
         "num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
         "num_key_value_heads": 2, "intermediate_size": 128,
         "vocab_size": 256, "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
@@ -35,11 +36,15 @@ LIMIT = {"max_logit_gap": 0.05}
 
 
 def run(kind: str, seed: int = 3_000_000_011, seconds: float = 3.0,
-        **kw):
+        cfg: dict = None, limits: dict = LIMIT, **kw):
+    """One run of the tiny cell ``kind``, of the dense tiny configuration
+    or of ``cfg``; returns serve_cell.run's triple and the counter."""
     cell = {"name": f"tiny.{kind}", "config": "tiny", "traffic": kind,
             "chips": 1}
     counter = core.CompileCounter()
-    return serve_cell.run(cell, cfg_file(kind == "batch"), seed, seconds,
-                          time.perf_counter(), counter,
-                          mix=MIXES[kind], limits=LIMIT, peak=PEAK,
+    cfg = cfg or cfg_file(kind == "batch")
+    model = core.load_model(cfg, f"configuration of {cell['name']}")
+    return serve_cell.run(cell, cfg, model, seed,
+                          seconds, time.perf_counter(), counter,
+                          mix=MIXES[kind], limits=limits, peak=PEAK,
                           **kw) + (counter,)

@@ -16,7 +16,12 @@ launches matched in order).
 
 Op events are named by their HLO instruction (``%paged_attention.1 =
 bf16[...] custom-call(...)``); ops are counted under the instruction's
-name without its ``.N`` suffix (``paged_attention``).
+name without its ``.N`` suffix (``paged_attention``), and under (module,
+instruction): each op goes to the program run that encloses its start,
+so that ``scope_map`` of the programs' HLO texts can give each the
+model scope it was traced in: the innermost of the ``jax.named_scope``
+names that the run's model module declares (``SCOPES`` of
+``bench/models/<model>.py``) in the instruction's ``op_name``.
 
 ``reduce_planes`` works on any objects with the attributes of
 ``jax.profiler.ProfileData`` (planes -> lines -> events with ``name``,
@@ -25,11 +30,13 @@ name without its ``.N`` suffix (``paged_attention``).
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import os
+import re
 from collections import defaultdict
-from typing import Dict, List, Optional, Tuple
+from typing import Collection, Dict, Iterable, List, Optional, Tuple
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
@@ -40,6 +47,12 @@ LAUNCH = "PJRT_LoadedExecutable_Execute"
 # control-flow ops whose events enclose the ops of their bodies: counted
 # in busy time, left out of per-op time (their bodies are counted)
 CONTAINERS = ("while", "conditional", "call")
+UNSCOPED = "none"
+UNMAPPED = "unmapped"          # an instruction the scope map lacks
+
+_MODULE = re.compile(r"HloModule\s+([^\s,]+)")
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 @dataclasses.dataclass
@@ -52,6 +65,9 @@ class TraceSummary:
     module_s: Dict[str, float]           # per jitted program, summed
     module_count: Dict[str, int]
     gaps: List[Tuple[str, float]]        # idle gaps, longest first
+    # per (module, instruction), summed over chips (``op_time``)
+    instr_s: Dict[Tuple[str, str], float] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def idle_share(self) -> float:
@@ -87,6 +103,92 @@ def _op(name: str) -> str:
     head = name.split(" = ", 1)[0].strip().lstrip("%")
     stem, _, tail = head.rpartition(".")
     return stem if stem and tail.isdigit() else head
+
+
+def instruction(event_name: str) -> str:
+    """``%fusion.12 = bf16[4] fusion(..)`` -> ``fusion.12``."""
+    return event_name.split(" = ", 1)[0].strip().lstrip("%")
+
+
+def scope_of(op_name: str, scopes: Collection[str]) -> str:
+    """The innermost of ``scopes`` in an ``op_name`` path, e.g.
+    ``jit(f)/while/body/attention/dot`` -> ``attention``."""
+    found = UNSCOPED
+    for part in op_name.split("/"):
+        if part in scopes:
+            found = part
+    return found
+
+
+def scope_map(hlo: Dict[str, str],
+              scopes: Iterable[str]) -> Dict[Tuple[str, str], str]:
+    """(module, instruction) -> the innermost of the model's ``scopes``
+    over HLO texts.  Programs that share a module name (the prefill
+    chunk sizes) and give one instruction name different scopes map it
+    to ``mixed``."""
+    scopes = frozenset(scopes)
+    out: Dict[Tuple[str, str], str] = {}
+    for text in hlo.values():
+        m = _MODULE.search(text)
+        if not m:
+            continue
+        module = m.group(1)
+        for line in text.splitlines():
+            ins = _INSTR.match(line)
+            if not ins:
+                continue
+            op = _OP_NAME.search(line)
+            sc = scope_of(op.group(1), scopes) if op else UNSCOPED
+            key = (module, ins.group(1))
+            out[key] = sc if out.get(key, sc) == sc else "mixed"
+    return out
+
+
+def scope_seconds(instr_s: Dict[Tuple[str, str], float],
+                  smap: Dict[Tuple[str, str], str],
+                  program: str) -> Dict[str, float]:
+    """Seconds of each scope in the modules whose name holds
+    ``program``; an instruction the map lacks counts as ``unmapped``."""
+    out: Dict[str, float] = defaultdict(float)
+    for (module, ins), t in instr_s.items():
+        if program in module:
+            out[smap.get((module, ins), UNMAPPED)] += t
+    return dict(out)
+
+
+def device_events(plane, shift: int, lo: int, hi: int):
+    """Module runs ``(start, end, base name)`` and ops ``(start, end,
+    event name)`` of one device plane that overlap [lo, hi), on the
+    host's clock, each sorted by start."""
+    mods, ops = [], []
+    for line in plane.lines:
+        if line.name not in (OPS_LINE, MODULES_LINE):
+            continue
+        for e in line.events:
+            a = e.start_ns + shift
+            b = a + e.duration_ns
+            if b <= lo or a >= hi:
+                continue
+            if line.name == MODULES_LINE:
+                mods.append((a, b, _base(e.name)))
+            else:
+                ops.append((a, b, e.name))
+    return sorted(mods), sorted(ops)
+
+
+def op_time(mods, ops, lo: int, hi: int) -> Dict[Tuple[str, str], float]:
+    """Seconds in [lo, hi) of each (module, instruction), each op under
+    the module run that encloses its start; control-flow ops that
+    enclose their bodies are left out."""
+    starts = [m[0] for m in mods]
+    out: Dict[Tuple[str, str], float] = defaultdict(float)
+    for a, b, name in ops:
+        if _op(name) in CONTAINERS:
+            continue
+        i = bisect.bisect_right(starts, a) - 1
+        module = mods[i][2] if i >= 0 and mods[i][1] >= a else ""
+        out[(module, instruction(name))] += (min(b, hi) - max(a, lo)) * 1e-9
+    return dict(out)
 
 
 def _launches(planes, lo, hi):
@@ -174,30 +276,22 @@ def reduce_planes(planes, window: Optional[Tuple[int, int]] = None,
     op_n: Dict[str, int] = defaultdict(int)
     mod_s: Dict[str, float] = defaultdict(float)
     mod_n: Dict[str, int] = defaultdict(int)
+    instr_s: Dict[Tuple[str, str], float] = defaultdict(float)
     busy = 0.0
     gaps = []
     launches = _launches(planes, lo, hi)
     for p in devices:
-        ivs = []
-        shift = _shift(p, launches)
-        for line in p.lines:
-            if line.name not in (OPS_LINE, MODULES_LINE):
-                continue
-            for e in line.events:
-                a = e.start_ns + shift
-                b = a + e.duration_ns
-                if b <= lo or a >= hi:
-                    continue
-                dur = (min(b, hi) - max(a, lo)) * 1e-9
-                if line.name == OPS_LINE:
-                    ivs.append((a, b))
-                    if _op(e.name) not in CONTAINERS:
-                        op_s[_op(e.name)] += dur
-                        op_n[_op(e.name)] += 1
-                else:
-                    mod_s[_base(e.name)] += dur
-                    mod_n[_base(e.name)] += 1
-        merged = _clip(_union(ivs), lo, hi)
+        mods, ops = device_events(p, _shift(p, launches), lo, hi)
+        for a, b, name in mods:
+            mod_s[name] += (min(b, hi) - max(a, lo)) * 1e-9
+            mod_n[name] += 1
+        for a, b, name in ops:
+            if _op(name) not in CONTAINERS:
+                op_s[_op(name)] += (min(b, hi) - max(a, lo)) * 1e-9
+                op_n[_op(name)] += 1
+        for key, t in op_time(mods, ops, lo, hi).items():
+            instr_s[key] += t
+        merged = _clip(_union([(a, b) for a, b, _ in ops]), lo, hi)
         busy += sum(b - a for a, b in merged) * 1e-9
         edges = [lo] + [x for iv in merged for x in iv] + [hi]
         for a, b in zip(edges[0::2], edges[1::2]):
@@ -208,7 +302,7 @@ def reduce_planes(planes, window: Optional[Tuple[int, int]] = None,
                         busy_s=busy / len(devices), chips=len(devices),
                         op_s=dict(op_s), op_count=dict(op_n),
                         module_s=dict(mod_s), module_count=dict(mod_n),
-                        gaps=gaps)
+                        gaps=gaps, instr_s=dict(instr_s))
 
 
 def find_xplane(log_dir: str) -> str:
